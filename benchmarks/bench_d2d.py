@@ -18,7 +18,7 @@ from jax.sharding import PartitionSpec as P
 
 from benchmarks.common import row, timeit
 from repro.core.topology import POD_LINK_BW, collective_seconds
-from repro.parallel.compat import shard_map
+from repro.launch.mesh import make_mesh
 
 LINK_LATENCY = 1e-6  # per-hop launch overhead (the paper's 61-cycle analogue)
 
@@ -27,13 +27,13 @@ def _measured_allreduce_rows():
     """psum over every host device as a 1-D pod axis; per-device buffer
     sizes kept CPU-friendly (the analytic model scales linearly anyway)."""
     n = jax.device_count()
-    mesh = jax.make_mesh((n,), ("pod",))
+    mesh = make_mesh((n,), ("pod",))
     for mbytes in (1, 4, 16):
         per_dev = mbytes * (1 << 20)
         elems = per_dev // 4
         x = jnp.ones((n * elems,), jnp.float32)
         f = jax.jit(
-            shard_map(
+            jax.shard_map(
                 lambda v: jax.lax.psum(v, "pod"),
                 mesh=mesh, in_specs=P("pod"), out_specs=P("pod"),
                 check_vma=False,

@@ -95,7 +95,9 @@ def main(argv=None) -> None:
     import jax
 
     from repro.kernels import registry
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     tune = (args.autotune or args.autotune_only
             or os.environ.get("REPRO_AUTOTUNE") == "1")
 

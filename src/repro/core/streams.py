@@ -32,12 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; resolve the
-# one this jax ships so kernels never touch the name directly.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
 
 def _dtype_bytes(dtype) -> int:
     # dtype is cost metadata only; streams without it are counted at the
@@ -228,7 +222,7 @@ def stream_compute(program: StreamProgram, *operands, interpret: bool = False):
 
     kwargs: dict = {"out_shape": out_shapes, "interpret": interpret}
     if program.dimension_semantics is not None and not interpret:
-        kwargs["compiler_params"] = _CompilerParams(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=tuple(program.dimension_semantics)
         )
 
@@ -291,7 +285,7 @@ def remote_ring_hop(x: jax.Array, axis: str, n: int) -> jax.Array:
         in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
         out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
         scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             has_side_effects=True, collective_id=0
         ),
     )(x)

@@ -314,10 +314,8 @@ def _decode_xla(q, k, v, position, *, window, scale, precision=None, bs=None,
     )
 
 
-# decode is memory-bound and already linear; the ref form stands in for the
-# stream impls (the blocked xla form above carries the cache-tile geometry).
-@registry.register_kernel("decode_attention", impl="pallas")
-@registry.register_kernel("decode_attention", impl="interpret")
+# decode has no stream kernel yet: on TPU ``auto`` runs the blocked xla form
+# above, and ``pallas``/``interpret`` are not registered.
 @registry.register_kernel("decode_attention", impl="ref")
 def _decode_ref(q, k, v, position, *, window, scale, precision=None, bs=None,
                 block_table=None, k_scale=None, v_scale=None, pos_offset=0,
@@ -360,7 +358,8 @@ def linear_attention(r, k, v, w_log, u=None, s0=None, *, impl=None, mesh=None,
     """
     chunk = resolve_blocks("linear_attention", chunk=chunk)["chunk"]
     # ref runs the exact per-token scan and never exponentiates a chunk span
-    if resolve_impl(impl) != "ref" and chunk * -W_LOG_FLOOR > _MAX_CHUNK_EXP:
+    exact = resolve_impl(impl, "linear_attention") == "ref"
+    if not exact and chunk * -W_LOG_FLOOR > _MAX_CHUNK_EXP:
         raise ValueError(
             f"chunk={chunk} overflows fp32: chunk * |W_LOG_FLOOR| = "
             f"{chunk * -W_LOG_FLOOR} must stay <= {_MAX_CHUNK_EXP} "

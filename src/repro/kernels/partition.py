@@ -16,9 +16,9 @@ Layering (parallel to impl selection and block resolution):
                     or the mesh from ``sharding.use_mesh`` — and routes here
   partition.py      plan_for(): PartitionRule -> PartitionPlan (specs +
                     local function + per-level collective-cost metadata)
-  sharded_call()    wraps WHICHEVER registered impl runs in ``shard_map``
-                    (via parallel/compat), so pallas, interpret, xla and ref
-                    all execute the identical sharded program; the single
+  sharded_call()    wraps WHICHEVER registered impl runs in
+                    ``jax.shard_map``, so pallas, interpret, xla and ref all
+                    execute the identical sharded program; the single
                     pallas-call-site invariant (core/streams.py) is untouched
   consumers         launch/roofline prices plan.collectives per level with
                     ``topology.collective_seconds`` (on-chiplet vs D2D
@@ -85,7 +85,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.diagnostics import warn_degrade
 from repro.kernels import registry
 from repro.parallel.collectives import hierarchical_psum
-from repro.parallel.compat import shard_map
 
 # The complete mesh-axis vocabulary the partition layer ever shards over or
 # names in a collective: the D2D pod link, the group interconnect (data),
@@ -421,7 +420,7 @@ def sharded_call(op: str, mesh, *args, impl: str | None = None, **kwargs):
     This is the single seam ops.py routes mesh-aware calls through — no
     per-call spec plumbing anywhere else.
     """
-    impl = registry.resolve_impl(impl)
+    impl = registry.resolve_impl(impl, op)
     plan = plan_for(op, mesh, *args, impl=impl, **kwargs)
     if plan is None:
         return registry.kernel_call(
@@ -443,7 +442,7 @@ def sharded_call(op: str, mesh, *args, impl: str | None = None, **kwargs):
             full[i] = v
         return plan.local_fn(*full)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         wrapped, mesh=mesh, in_specs=in_specs, out_specs=plan.out_specs,
         check_vma=False,
     )

@@ -10,7 +10,11 @@ Each op registers up to four implementations:
   - ``ref``:       the naive oracle from ref.py
 
 Selection precedence: explicit ``impl=`` argument > ``set_default_impl()`` >
-``REPRO_KERNEL_IMPL`` env var > auto (pallas on TPU backends, xla elsewhere).
+``REPRO_KERNEL_IMPL`` env var > auto. ``auto`` resolves per op: on a TPU
+backend, ``pallas`` where the op registers a real kernel and ``xla`` where it
+does not; ``xla`` on every other backend. No name stands for another form:
+an op without a ``pallas`` kernel has no ``pallas`` entry, and asking for one
+raises.
 
 The registry also owns the per-op default block-size table with an override
 layer (``set_block_override``) — the seam a future autotuner writes through —
@@ -100,12 +104,19 @@ def default_impl(impl: str | None):
         _default_impl = old
 
 
-def resolve_impl(impl: str | None = None) -> str:
+def resolve_impl(impl: str | None = None, op: str | None = None) -> str:
+    """The impl name a call dispatches to. With ``op``, ``auto`` resolves
+    for that op (``pallas`` only where the op has a kernel); without it,
+    ``auto`` names the backend's preferred form."""
     impl = impl or _default_impl or os.environ.get("REPRO_KERNEL_IMPL", "auto")
     if impl not in VALID_IMPLS:
         raise ValueError(f"unknown impl {impl!r}; one of {VALID_IMPLS}")
     if impl == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
+        if jax.default_backend() != "tpu":
+            return "xla"
+        if op is None:
+            return "pallas"
+        return "pallas" if "pallas" in implementations(op) else "xla"
     return impl
 
 
@@ -115,7 +126,7 @@ def kernel_call(op: str, *args, impl: str | None = None, **kwargs):
         raise KeyError(
             f"unknown kernel op {op!r}; registered ops: {registered_ops()}"
         )
-    impl = resolve_impl(impl)
+    impl = resolve_impl(impl, op)
     fn = _REGISTRY[op].get(impl)
     if fn is None:
         raise NotImplementedError(

@@ -26,7 +26,8 @@ HEADER = """\
      CI runs `python -m repro.launch.docgen --check` and fails on drift. -->
 
 Every op dispatches through `kernels/ops.py` along three axes: **impl**
-(pallas / interpret / xla / ref, resolved by `registry.resolve_impl`),
+(pallas / interpret / xla / ref, resolved per op by `registry.resolve_impl`;
+`auto` is pallas on TPU where the op has a kernel, xla otherwise),
 **block geometry** (`registry.resolve_blocks`: explicit kwarg >
 `set_block_override` > table default), and **partitioning**
 (`kernels/partition.py`: the op's `PartitionRule` resolved against the

@@ -4,23 +4,32 @@ Axis mapping (DESIGN.md C5): `model` = intra-chiplet crossbar (TP),
 `data` = group level (DP/FSDP/SP), `pod` = D2D link (second DP axis).
 A FUNCTION, not a module constant: importing this module never touches jax
 device state.
+
+Every mesh in the repository is built by ``make_mesh``: its axes are
+``Auto``, so shardings propagate through the compiler and
+``with_sharding_constraint`` may name any axis. (``jax.make_mesh`` itself
+defaults to ``Explicit`` axes, which the sharding rules here do not use.)
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from repro.diagnostics import warn_degrade
+
+
+def make_mesh(shape: tuple, axes: tuple, *, devices=None):
+    """A mesh of ``shape`` named ``axes`` with ``Auto`` axis types, over
+    ``devices`` (default: every local device, in ``jax.make_mesh`` order)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes), devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape: tuple, axes: tuple):
-    """Arbitrary meshes (tests, elastic re-meshing, hillclimb variants)."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def host_device_mesh(tp: int = 1, pods: int = 1):
@@ -57,7 +66,5 @@ def host_device_mesh(tp: int = 1, pods: int = 1):
             f"divide {n} devices; degrading to tp={tp}, pods={pods}",
         )
     if want_pods == 1:
-        return jax.make_mesh((n // tp, tp), ("data", "model"))
-    return jax.make_mesh(
-        (pods, per_pod // tp, tp), ("pod", "data", "model")
-    )
+        return make_mesh((n // tp, tp), ("data", "model"))
+    return make_mesh((pods, per_pod // tp, tp), ("pod", "data", "model"))

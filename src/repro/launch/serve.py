@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry, transformer, multimodal
 
 
@@ -81,11 +82,13 @@ def generate(cfg, params, tokens, gen_len: int, max_len: int,
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="occamy-gptj")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="the tiny same-family config instead of the published one")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     rng = np.random.default_rng(0)

@@ -9,9 +9,9 @@ from __future__ import annotations
 
 import argparse
 
-import jax
-
 from repro.configs.base import SHAPES, get_config
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.runtime import train_loop
 from repro.runtime.fault_tolerance import FailureInjector
 
@@ -32,13 +32,14 @@ def main():
     ap.add_argument("--mesh", default=None, help="e.g. 1x1 / 4x2 (data x model)")
     ap.add_argument("--inject-crash-at", type=int, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     shape = SHAPES[args.shape]
     mesh = None
     if args.mesh:
         d, m = (int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
 
     injector = (
         FailureInjector({args.inject_crash_at: "crash"})

@@ -85,7 +85,9 @@ def mlp(p: dict, x: jax.Array, activation: str) -> jax.Array:
 
 
 def dense_init(rng, shape, scale=None, dtype=jnp.bfloat16):
-    scale = scale if scale is not None else 1.0 / np.sqrt(shape[0])
+    """Normal init scaled by 1/sqrt(fan-in). The fan-in is ``shape[-2]``:
+    weights are (..., in, out), with stacked layers or experts in front."""
+    scale = scale if scale is not None else 1.0 / np.sqrt(shape[-2])
     return (jax.random.normal(rng, shape, jnp.float32) * scale).astype(dtype)
 
 

@@ -23,7 +23,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.models import layers as L
-from repro.parallel.compat import shard_map
 from repro.parallel.sharding import constrain, current_mesh, dp_axes
 
 
@@ -138,7 +137,7 @@ def moe_mlp(p, x, cfg):
     ) == 0
     if use_shard_map:
         dp = dp_axes(mesh)
-        disp, slot, order = shard_map(
+        disp, slot, order = jax.shard_map(
             dispatch_rows,
             mesh=mesh,
             in_specs=(P(dp, None, None), P(dp, None, None)),
@@ -173,7 +172,7 @@ def moe_mlp(p, x, cfg):
     y = constrain(y, "moe_dispatch")
 
     if use_shard_map:
-        out = shard_map(
+        out = jax.shard_map(
             combine_rows,
             mesh=mesh,
             in_specs=(P(dp, None, None, None), P(dp, None), P(dp, None),

@@ -10,6 +10,8 @@
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -29,7 +31,10 @@ def _family_mod(cfg: ModelConfig):
     }[cfg.family]
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def init_params(cfg, rng):
+    """One compiled program: each weight is drawn, scaled and cast in one
+    fused pass, so no float32 temporary of a whole weight is ever held."""
     return _family_mod(cfg).init_params(cfg, rng)
 
 

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 
 from repro.kernels import ops
 from repro.models import layers as L
-from repro.parallel.compat import shard_map
 from repro.parallel.sharding import constrain
 
 LORA_RANK = 64
@@ -97,7 +96,7 @@ def _shift(x, cfg=None):  # (B, S, d): x_prev[t] = x[t-1]; zero at seq start
             )  # rank 0 receives zeros == sequence start
             return jnp.concatenate([prev, xl[:, :-1, :]], axis=1)
 
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=P(dp, "model", None), out_specs=P(dp, "model", None),
             check_vma=False,

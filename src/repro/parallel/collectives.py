@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.diagnostics import warn_degrade
-from repro.parallel.compat import shard_map
 
 # matches the flash kernels' masked-score floor: fully-masked softmax rows
 # carry lse ~= NEG, which the online merge weights to exp(NEG - NEG) ~ 1
@@ -95,7 +94,7 @@ def ep_expert_ffn(disp, wi, wg, wo, act, mesh, dp, *, ep_axis="model"):
 
     has_gate = wg is not None
     if has_gate:
-        return shard_map(
+        return jax.shard_map(
             lambda d_, wi_, wg_, wo_: local(d_, wi_, wg_, wo_),
             mesh=mesh,
             in_specs=(P(dp, None, None, None), P(ep_axis, None, None),
@@ -103,7 +102,7 @@ def ep_expert_ffn(disp, wi, wg, wo, act, mesh, dp, *, ep_axis="model"):
             out_specs=P(dp, None, None, None),
             check_vma=False,
         )(disp, wi, wg, wo)
-    return shard_map(
+    return jax.shard_map(
         lambda d_, wi_, wo_: local(d_, wi_, None, wo_),
         mesh=mesh,
         in_specs=(P(dp, None, None, None), P(ep_axis, None, None),

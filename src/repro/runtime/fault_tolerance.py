@@ -68,10 +68,10 @@ def elastic_remesh(data_parallel: int, model_parallel: int, lost_ranks: int = 0)
     whatever devices remain. Returns (mesh, new_data_parallel)."""
     new_dp = data_parallel - lost_ranks
     assert new_dp >= 1, "cannot shrink below one data-parallel rank"
-    devices = np.asarray(jax.devices()[: new_dp * model_parallel])
-    mesh = jax.sharding.Mesh(
-        devices.reshape(new_dp, model_parallel), ("data", "model")
-    )
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((new_dp, model_parallel), ("data", "model"),
+                     devices=jax.devices()[: new_dp * model_parallel])
     return mesh, new_dp
 
 

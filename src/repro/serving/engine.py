@@ -78,7 +78,19 @@ class StubModel:
 
 class PagedModel:
     """The real model half: jitted paged prefill + decode over a
-    ``PagedKVCache`` (dense/moe transformer families)."""
+    ``PagedKVCache`` (dense/moe transformer families).
+
+    On one device the cache pages are the scheduler's own block ids. With
+    a ``mesh``, decode attention is the ring over ``mesh[ring_axis]``
+    (``serving.ring_decode``), which needs every sequence's logical block
+    j on shard ``j // NB_l`` (``NB_l = max_blocks_per_seq / n``). The
+    scheduler's ids carry no placement, so the model lays the pool out by
+    slot instead: each shard holds a scratch page plus ``NB_l`` pages per
+    slot, and slot s keeps logical block j in page
+    ``(j // NB_l) * shard_pages + 1 + s * NB_l + j % NB_l``. The weights are
+    placed replicated and the pools sharded on their page axis once, here,
+    and every step keeps them there.
+    """
 
     def __init__(self, cfg, params, *, num_blocks, block_size, max_slots,
                  max_blocks_per_seq, precision=None, impl=None, mesh=None,
@@ -103,39 +115,69 @@ class PagedModel:
         self.impl = impl
         self.mesh = mesh
         self.ring_axis = ring_axis
-        self.cache = paged_cache.init_paged_cache(
-            cfg, num_blocks=num_blocks, block_size=block_size,
-            policy=None if precision is None else getattr(
-                precision, "name", precision
-            ),
-        )
         self.tables = np.full(
             (max_slots, max_blocks_per_seq), NULL_BLOCK, np.int32
         )
         attn_fn = None
+        pool_blocks = num_blocks
+        self._pool_sharding = None
         if mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
             n = mesh.shape[ring_axis]
-            if num_blocks % n or max_blocks_per_seq % n:
+            if max_blocks_per_seq % n:
                 raise ValueError(
-                    "ring decode needs num_blocks and max_blocks_per_seq "
-                    f"divisible by the {ring_axis} axis ({n})"
+                    "ring decode needs max_blocks_per_seq divisible by the "
+                    f"{ring_axis} axis ({n})"
                 )
+            self._nb_l = max_blocks_per_seq // n
+            self._shard_pages = max_slots * self._nb_l + 1
+            pool_blocks = n * self._shard_pages
+            self._pool_sharding = NamedSharding(mesh, P(None, ring_axis))
+            self.params = jax.device_put(params, NamedSharding(mesh, P()))
+            shard_pages = self._shard_pages
 
             def attn_fn(q, kp, vp, ks, vs, tbl, pos, window):
+                # column j's page lies on its owning shard: reduce the
+                # global page id to that shard's local one
                 return ring_decode.ring_decode(
-                    q, kp, vp, tbl, pos, mesh, axis=ring_axis,
+                    q, kp, vp, tbl % shard_pages, pos, mesh, axis=ring_axis,
                     window=window, k_scale=ks, v_scale=vs, impl=impl,
                 )
 
-        self._attn_fn = attn_fn
-        self._decode_jit = jax.jit(
-            lambda p, c, b: transformer.decode_step_paged(
-                p, cfg, c, b, attn_fn=attn_fn
+        self.cache = self._place(paged_cache.init_paged_cache(
+            cfg, num_blocks=pool_blocks, block_size=block_size,
+            policy=None if precision is None else getattr(
+                precision, "name", precision
             ),
-            donate_argnums=(1,),
-        )
+        ))
+        self._attn_fn = attn_fn
+
+        def decode(p, c, b):
+            logits, c = transformer.decode_step_paged(
+                p, cfg, c, b, attn_fn=attn_fn
+            )
+            return logits, self._place(c)
+
+        self._decode_jit = jax.jit(decode, donate_argnums=(1,))
         self._prefill_jit: dict[int, object] = {}  # per length bucket
-        self._impl_ctx = impl
+        self.last_logits = None  # newest decode step's (max_slots, V_pad)
+
+    def _place(self, cache):
+        """Pin the pools to their page-axis sharding (no-op on one device):
+        once at construction, and as a constraint inside every step."""
+        if self._pool_sharding is None:
+            return cache
+        return self._jax.lax.with_sharding_constraint(cache,
+                                                      self._pool_sharding)
+
+    def _pages(self, slot, block_ids):
+        """Device page ids holding a sequence's logical blocks, in order."""
+        if self.mesh is None:
+            return list(block_ids)
+        nb_l = self._nb_l
+        return [(j // nb_l) * self._shard_pages + 1 + slot * nb_l + j % nb_l
+                for j in range(len(block_ids))]
 
     # -- prefill ------------------------------------------------------------
 
@@ -148,18 +190,30 @@ class PagedModel:
         if sb not in self._prefill_jit:
             nbp = sb // self.block_size
 
+            def prompt(params, tokens):
+                return tr.prefill_step(params, cfg, {"tokens": tokens},
+                                       max_len=sb)
+
+            if self.mesh is not None:
+                # every device prefills its own replica: a Pallas kernel is
+                # never partitioned by the compiler, only inside shard_map
+                from jax.sharding import PartitionSpec as P
+
+                prompt = jax.shard_map(prompt, mesh=self.mesh,
+                                       in_specs=(P(), P()),
+                                       out_specs=(P(), P()), check_vma=False)
+
             def run(params, cache, tokens, block_ids, last_idx):
                 # tokens (1, sb) padded prompt; causal attention keeps every
                 # real row independent of the padded tail
-                logits, kv = tr.prefill_step(params, cfg, {"tokens": tokens},
-                                             max_len=sb)
+                logits, kv = prompt(params, tokens)
                 nl, _, K, _, hd = kv["k"].shape
                 def rows(x):  # (nl, nbp, K, bs, hd)
                     return jnp.moveaxis(
                         x[:, 0].reshape(nl, K, nbp, self.block_size, hd), 2, 1
                     )
-                cache = cache.write_prompt(block_ids, rows(kv["k"]),
-                                           rows(kv["v"]))
+                cache = self._place(cache.write_prompt(
+                    block_ids, rows(kv["k"]), rows(kv["v"])))
                 first = jnp.argmax(
                     logits[0, last_idx, : cfg.vocab_size]
                 ).astype(jnp.int32)
@@ -174,14 +228,15 @@ class PagedModel:
         sb = self._bucket(len(prompt))
         tokens = np.zeros((1, sb), np.int32)
         tokens[0, : len(prompt)] = prompt
+        pages = self._pages(seq.slot, block_ids)
         ids = np.full(sb // self.block_size, NULL_BLOCK, np.int32)
-        ids[: len(block_ids)] = block_ids  # prompt pages (grant covers them)
+        ids[: len(pages)] = pages  # prompt pages (grant covers them)
         self.cache, first = self._prefill_fn(sb)(
             self.params, self.cache, jnp.asarray(tokens), jnp.asarray(ids),
             jnp.int32(len(prompt) - 1),
         )
         self.tables[seq.slot, :] = NULL_BLOCK
-        self.tables[seq.slot, : len(block_ids)] = block_ids
+        self.tables[seq.slot, : len(pages)] = pages
         return int(first)
 
     # -- decode -------------------------------------------------------------
@@ -189,7 +244,8 @@ class PagedModel:
     def sync_table(self, seq) -> None:
         """Mirror the scheduler's block list into the device table row."""
         self.tables[seq.slot, :] = NULL_BLOCK
-        self.tables[seq.slot, : len(seq.blocks)] = seq.blocks
+        self.tables[seq.slot, : len(seq.blocks)] = self._pages(seq.slot,
+                                                               seq.blocks)
 
     def decode(self, slot_tokens, slot_positions, slot_tables, active):
         jnp = self._jnp
@@ -199,6 +255,7 @@ class PagedModel:
             "block_table": jnp.asarray(slot_tables, jnp.int32),
         }
         logits, self.cache = self._decode_jit(self.params, self.cache, batch)
+        self.last_logits = logits
         return np.asarray(
             jnp.argmax(logits[:, : self.vocab], axis=-1)
         ).astype(np.int64)
@@ -207,14 +264,15 @@ class PagedModel:
 
     def save_blocks(self, seq, block_ids):
         jax = self._jax
-        ids = np.asarray(block_ids, np.int32)
+        ids = np.asarray(self._pages(seq.slot, block_ids), np.int32)
         return jax.device_get(self.cache.gather_blocks(ids))
 
     def restore_blocks(self, seq, block_ids, payload):
         jnp = self._jnp
         n = payload["k"].shape[1]
-        ids = jnp.asarray(np.asarray(block_ids[:n], np.int32))
-        self.cache = self.cache.restore_blocks(ids, payload)
+        ids = jnp.asarray(np.asarray(self._pages(seq.slot, block_ids[:n]),
+                                     np.int32))
+        self.cache = self._place(self.cache.restore_blocks(ids, payload))
 
 
 class ServingEngine:
@@ -311,7 +369,10 @@ class ServingEngine:
                 active[slot] = True
                 tokens[slot] = seq.generated[-1]
                 positions[slot] = seq.next_position()
-                tables[slot, : len(seq.blocks)] = seq.blocks
+                if hasattr(self.model, "sync_table"):
+                    self.model.sync_table(seq)
+                else:
+                    tables[slot, : len(seq.blocks)] = seq.blocks
             next_tokens = self.model.decode(tokens, positions, tables, active)
             for slot, seq in live.items():
                 sc.record_token(seq, int(next_tokens[slot]))

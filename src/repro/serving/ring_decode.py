@@ -30,7 +30,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.kernels import registry
 from repro.parallel import collectives
-from repro.parallel.compat import shard_map
 
 __all__ = ["ring_decode", "ring_decode_reference"]
 
@@ -117,7 +116,7 @@ def ring_decode(q, k_pool, v_pool, block_table, position, mesh, *,
             ks_l = vs_l = None
         return local(q_l, k_l, v_l, tbl_l, pos_l, ks_l, vs_l)
 
-    return shard_map(
+    return jax.shard_map(
         wrapped,
         mesh=mesh,
         in_specs=(P(None, None, None), pool_spec, pool_spec, P(None, axis),

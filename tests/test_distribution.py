@@ -18,6 +18,7 @@ _SUBPROCESS = textwrap.dedent(
     import json
     import jax
     import jax.numpy as jnp
+    from repro.launch.mesh import make_mesh
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.configs.base import all_arch_ids, get_config, SHAPES
@@ -25,7 +26,7 @@ _SUBPROCESS = textwrap.dedent(
     from repro.parallel import sharding as sh
     from repro.runtime import train_loop
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     out = {"specs_ok": [], "lowered": []}
 
     # 1) sharding rules produce valid NamedShardings for every FULL config
@@ -118,8 +119,9 @@ def test_halo_shift_matches_baseline_on_sharded_mesh():
         from repro.configs.base import get_config, SHAPES
         from repro.models import registry
         from repro.parallel import sharding as sh
+        from repro.launch.mesh import make_mesh
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg0 = get_config("rwkv6-3b", reduced=True)
         params = registry.init_params(cfg0, jax.random.PRNGKey(0))
         batch = registry.make_batch(cfg0, SHAPES["train_4k"],

@@ -133,3 +133,13 @@ def test_vocab_padding_never_predicted():
                             seq_override=8)
     loss = registry.loss_fn(params, cfg, b)
     assert jnp.isfinite(loss)  # padded tail masked to -1e30, not NaN
+
+
+def test_dense_init_scales_by_the_input_axis():
+    # stacked (layers, in, out): the fan-in is `in`, not the layer count
+    w = L.dense_init(jax.random.PRNGKey(0), (28, 256, 64), dtype=jnp.float32)
+    assert abs(float(jnp.std(w)) * 16 - 1) < 0.05
+    cfg = get_config("occamy-gptj", reduced=True)
+    params = registry.init_params(cfg, jax.random.PRNGKey(0))
+    wi = params["layers"]["wi"].astype(jnp.float32)
+    assert abs(float(jnp.std(wi)) * cfg.d_model ** 0.5 - 1) < 0.05

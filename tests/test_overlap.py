@@ -30,10 +30,11 @@ _OVERLAP_EQUIV = textwrap.dedent(
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
     from repro.kernels import ops, partition
+    from repro.launch.mesh import make_mesh
     from repro.parallel.collectives import ring_scan_carry
-    from repro.parallel.compat import shard_map
+    from jax import shard_map
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     rng = np.random.default_rng(0)
     f32 = jnp.float32
     out = {"ok": [], "exact": [], "notes": {}}

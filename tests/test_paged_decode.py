@@ -54,7 +54,7 @@ def _paged_setup(rng, *, B=3, H=8, K=4, S=64, D=16, bs=16, policy=None):
     return q, k, v, position, kp, vp, k_scale, v_scale, jnp.asarray(table)
 
 
-@pytest.mark.parametrize("impl", ["xla", "ref", "interpret"])
+@pytest.mark.parametrize("impl", ["xla", "ref", "auto"])
 @pytest.mark.parametrize("gqa_k", [1, 4])
 @pytest.mark.parametrize("window", [0, 13])
 def test_paged_bitwise_vs_contiguous(rng, impl, gqa_k, window):
@@ -304,6 +304,7 @@ _RING_SUBPROCESS = textwrap.dedent(
 
     from repro.kernels import ops
     from repro.serving import ring_decode as rd
+    from repro.launch.mesh import make_mesh
 
     rng = np.random.default_rng(0)
     B, H, K, D, bs, nb, n = 3, 8, 4, 16, 8, 8, 4
@@ -328,7 +329,7 @@ _RING_SUBPROCESS = textwrap.dedent(
                 nxt += 1
     kp, vp, tbl = jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tbl)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     got = rd.ring_decode(q, kp, vp, tbl, pos, mesh, axis="data")
     sync = rd.ring_decode(q, kp, vp, tbl, pos, mesh, axis="data",
                           overlap=False)
@@ -362,3 +363,67 @@ def test_ring_decode_8dev_bitwise_vs_reference():
     assert out["ring_vs_ref_bitwise"], out
     assert out["overlap_invariant"], out
     assert out["ring_vs_contig_err"] < 1e-5, out
+
+
+# ---------------------------------------------------------------------------
+# Engine: ring decode over data=4 serves the same tokens as one device
+# ---------------------------------------------------------------------------
+
+_ENGINE_RING_SUBPROCESS = textwrap.dedent(
+    """
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import json
+    import jax
+    import numpy as np
+
+    from repro.configs.base import get_config
+    from repro.launch.mesh import make_mesh
+    from repro.models import registry as mreg
+    from repro.serving.engine import Request, ServingEngine
+
+    cfg = get_config("occamy-gptj", reduced=True)
+    params = mreg.init_params(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(0)
+    # prompts long enough that their blocks span several shards
+    prompts = [tuple(rng.integers(0, cfg.vocab_size, n)) for n in
+               (5, 19, 33, 47)]
+    geo = dict(num_blocks=32, block_size=8, max_slots=4, max_blocks_per_seq=8)
+
+    def serve(mesh):
+        eng = ServingEngine.with_model(cfg, params, mesh=mesh, **geo)
+        for i, p in enumerate(prompts):
+            eng.submit(Request(rid=i, prompt=p, max_new_tokens=6))
+        return eng, eng.run()
+
+    _, want = serve(None)
+    eng, got = serve(make_mesh((4,), ("data",)))
+    pool = eng.model.cache.k_pool
+    out = {
+        "same_tokens": want == got,
+        "leaked": eng.leaked_blocks(),
+        "pool_spec": str(pool.sharding.spec),
+        "pool_pages": int(pool.shape[1]),
+    }
+    print("RESULT:" + json.dumps(out))
+    """
+)
+
+
+@pytest.mark.slow
+def test_engine_ring_decode_serves_same_tokens_4dev():
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    proc = subprocess.run(
+        [sys.executable, "-c", _ENGINE_RING_SUBPROCESS],
+        capture_output=True, text=True, env=env, timeout=900,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    line = [ln for ln in proc.stdout.splitlines()
+            if ln.startswith("RESULT:")][-1]
+    out = json.loads(line[len("RESULT:"):])
+    assert out["same_tokens"], out
+    assert out["leaked"] == 0, out
+    # pools sharded on their page axis: 4 shards of (1 scratch + 4 slots x 2)
+    assert out["pool_pages"] == 4 * (1 + 4 * 2), out
+    assert out["pool_spec"] == "PartitionSpec(None, 'data')", out

@@ -499,10 +499,11 @@ _EQUIV = textwrap.dedent(
     import jax, jax.numpy as jnp, numpy as np
     from repro.core import sparse as sp
     from repro.kernels import ops, partition
+    from repro.launch.mesh import make_mesh
     from repro.models import gcn
     from repro.parallel import sharding as sh
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(0)
     f32 = jnp.float32
     out = {"ok": [], "fallbacks": []}
@@ -536,9 +537,9 @@ _EQUIV = textwrap.dedent(
     offs = np.array([(-2, 0, 0), (0, 0, 0), (1, 1, 0), (2, 0, 1)], np.int32)
     w = np.array([0.2, 0.3, 0.4, 0.1], np.float32)
 
-    # decode_attention's stream impls are the ref form, so all four impl
-    # names run on CPU for it; stream ops cover interpret/xla/ref (the
-    # pallas entry is the same StreamProgram, compiled)
+    # stream ops cover interpret/xla/ref (the pallas entry is the same
+    # StreamProgram, compiled); decode_attention has no stream kernel, so
+    # it covers auto/xla/ref
     for impl in ("interpret", "xla", "ref"):
         check(f"gemm[{impl}]",
               ops.gemm(a, b, mesh=mesh, impl=impl, out_dtype=f32),
@@ -563,7 +564,7 @@ _EQUIV = textwrap.dedent(
         check(f"stencil[{impl}]",
               ops.stencil(grid, offs, w, mesh=mesh, impl=impl),
               ops.stencil(grid, offs, w, impl="ref"))
-    for impl in ("pallas", "interpret", "xla", "ref"):
+    for impl in ("auto", "xla", "ref"):
         check(f"decode[{impl}]",
               ops.decode_attention(qd, kv, kv, pos, mesh=mesh, impl=impl),
               ops.decode_attention(qd, kv, kv, pos, impl="ref"))
@@ -591,7 +592,7 @@ _EQUIV = textwrap.dedent(
 
     # halo exchange at every slab width that divides X=16
     for tp in (2, 4, 8):
-        m2 = jax.make_mesh((8 // tp, tp), ("data", "model"))
+        m2 = make_mesh((8 // tp, tp), ("data", "model"))
         check(f"stencil_halo_tp{tp}",
               ops.stencil(grid, offs, w, mesh=m2, impl="interpret"),
               ops.stencil(grid, offs, w, impl="ref"))
@@ -627,7 +628,7 @@ def test_sharded_equivalence_all_ops():
                    "bsr_spmm", "spmspm", "stencil"):
         for impl in ("interpret", "xla", "ref"):
             assert f"{op_tag}[{impl}]" in out["ok"], (op_tag, impl)
-    for impl in ("pallas", "interpret", "xla", "ref"):
+    for impl in ("auto", "xla", "ref"):
         assert f"decode[{impl}]" in out["ok"]
     assert set(out["fallbacks"]) == {"flash", "spmm"}
     assert {"stencil_halo_tp2", "stencil_halo_tp4", "stencil_halo_tp8",
@@ -646,8 +647,9 @@ _EQUIV_3AX = textwrap.dedent(
     import jax, jax.numpy as jnp, numpy as np
     from repro.core import sparse as sp
     from repro.kernels import ops, partition
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     rng = np.random.default_rng(0)
     f32 = jnp.float32
     out = {"ok": [], "two_level": [], "ladder": []}
@@ -744,7 +746,7 @@ _EQUIV_3AX = textwrap.dedent(
         check(f"stencil[{impl}]",
               ops.stencil(grid, offs, w, mesh=mesh, impl=impl),
               ops.stencil(grid, offs, w, impl="ref"))
-    for impl in ("pallas", "interpret", "xla", "ref"):
+    for impl in ("auto", "xla", "ref"):
         check(f"decode[{impl}]",
               ops.decode_attention(qd, kv, kv, pos, mesh=mesh, impl=impl),
               ops.decode_attention(qd, kv, kv, pos, impl="ref"))
@@ -767,7 +769,7 @@ def test_sharded_equivalence_all_ops_three_axis():
                    "bsr_spmm", "spmspm", "stencil"):
         for impl in ("interpret", "xla", "ref"):
             assert f"{op_tag}[{impl}]" in out["ok"], (op_tag, impl)
-    for impl in ("pallas", "interpret", "xla", "ref"):
+    for impl in ("auto", "xla", "ref"):
         assert f"decode[{impl}]" in out["ok"]
     # the joint pod x model plans actually engaged (not a silent fallback)
     assert set(out["two_level"]) == {"gemm", "flash", "decode", "linattn",
@@ -919,10 +921,11 @@ _EQUIV_RING = textwrap.dedent(
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
     from repro.kernels import ops, partition
+    from repro.launch.mesh import make_mesh
     from repro.parallel.collectives import ring_scan_carry
-    from repro.parallel.compat import shard_map
+    from jax import shard_map
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     rng = np.random.default_rng(0)
     f32 = jnp.float32
     out = {"ok": [], "ring": [], "batch": []}

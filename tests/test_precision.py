@@ -151,7 +151,7 @@ def test_scaled_decode_attention_cross_impl(rng, pol, tol):
     oracle = ref.decode_attention_ref(q, kv, kv, pos)
     outs = {
         impl: ops.decode_attention(q, kv, kv, pos, precision=pol, impl=impl)
-        for impl in ("xla", "interpret", "ref")
+        for impl in ("xla", "auto", "ref")
     }
     for impl, got in outs.items():
         assert _rel(got, oracle) < tol, (impl, _rel(got, oracle))
@@ -302,6 +302,7 @@ _SHARDED = textwrap.dedent(
     import json
     import jax, jax.numpy as jnp, numpy as np
     from repro.kernels import ops, partition
+    from repro.launch.mesh import make_mesh
 
     rng = np.random.default_rng(0)
     f32 = jnp.float32
@@ -318,7 +319,7 @@ _SHARDED = textwrap.dedent(
     # quantization + fp32 accumulate + bf16-reduce psum epilogue
     a = jnp.asarray(rng.standard_normal((64, 256)), f32)
     b = jnp.asarray(rng.standard_normal((256, 48)), f32)
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     plan = partition.plan_for("gemm", mesh, a, b, precision="fp8")
     assert "k-sharded" in plan.note and "bfloat16 reduce" in plan.note, plan.note
     want = ops.gemm(a, b, impl="ref", out_dtype=f32)
@@ -331,7 +332,7 @@ _SHARDED = textwrap.dedent(
     # fp8 flash over batch x kv-head sharding (data=2, model=4)
     q = jnp.asarray(rng.standard_normal((2, 8, 32, 16)), f32)
     kv = jnp.asarray(rng.standard_normal((2, 4, 32, 16)), f32)
-    mesh8 = jax.make_mesh((2, 4), ("data", "model"))
+    mesh8 = make_mesh((2, 4), ("data", "model"))
     want = ops.flash_attention(q, kv, kv, impl="ref")
     for impl in ("xla", "interpret"):
         got = ops.flash_attention(q, kv, kv, mesh=mesh8, impl=impl,
@@ -342,7 +343,7 @@ _SHARDED = textwrap.dedent(
     # quantization inside the ring fold
     q1 = jnp.asarray(rng.standard_normal((1, 4, 64, 16)), f32)
     kv1 = jnp.asarray(rng.standard_normal((1, 2, 64, 16)), f32)
-    mesh42 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh42 = make_mesh((4, 2), ("data", "model"))
     plan = partition.plan_for("flash_attention", mesh42, q1, kv1, kv1,
                               precision="fp8")
     assert "ring seq-parallel" in plan.note, plan.note

@@ -43,10 +43,27 @@ def test_register_rejects_auto():
 
 def test_all_ops_registered_with_all_impls():
     for op in ("gemm", "flash_attention", "linear_attention", "spmm",
-               "bsr_spmm", "spmspm", "stencil", "decode_attention"):
+               "bsr_spmm", "spmspm", "stencil"):
         assert registry.implementations(op) == [
             "interpret", "pallas", "ref", "xla"
         ], op
+    # no stream kernel yet: no name may stand in for one
+    assert registry.implementations("decode_attention") == ["ref", "xla"]
+    with pytest.raises(NotImplementedError, match="no 'pallas'"):
+        registry.kernel_call("decode_attention", impl="pallas")
+
+
+def test_auto_resolves_per_op_on_tpu(monkeypatch):
+    # steer the backend the resolver sees; no TPU is needed to check it
+    monkeypatch.delenv("REPRO_KERNEL_IMPL", raising=False)
+    monkeypatch.setattr(registry.jax, "default_backend", lambda: "tpu")
+    assert registry.resolve_impl(None, "flash_attention") == "pallas"
+    assert registry.resolve_impl(None, "gemm") == "pallas"
+    assert registry.resolve_impl(None, "decode_attention") == "xla"
+    assert registry.resolve_impl("ref", "flash_attention") == "ref"
+    monkeypatch.setattr(registry.jax, "default_backend", lambda: "cpu")
+    assert registry.resolve_impl(None, "flash_attention") == "xla"
+    assert registry.resolve_impl(None, "decode_attention") == "xla"
 
 
 def test_impl_precedence_env_default_arg(monkeypatch):

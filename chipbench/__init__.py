@@ -1,0 +1,1 @@
+"""Chip benchmark: one cell of ``BENCHMARK.json`` per run (see run.py)."""

@@ -2,9 +2,10 @@
 
 Drives the ``repro.serving`` engine — continuous-batching scheduler over a
 paged KV cache — with a seeded open-loop arrival process and reports the
-serving figures of merit: decode throughput (tok/s), request latency
-percentiles (p50/p99, in *engine steps* — virtual time), preemption and
+serving figures of merit: decode throughput (tok/s), preemption and
 admission counts, and the block-ledger audit (leaked blocks must be 0).
+Request latency in seconds on the chip is the chip benchmark's
+(``chipbench/``).
 
 Arrivals are Poisson in virtual time: request r arrives at step
 ``cumsum(Exp(1/lam))_r`` — deterministic given ``--seed``. EOS is disabled,
@@ -17,8 +18,8 @@ The committed baseline is produced by::
 
     PYTHONPATH=src python -m benchmarks.bench_serve --json BENCH_serve.json
 
-``--smoke`` asserts the CI serving-job invariants (nonzero completions,
-zero leaked blocks, finite p99) and exits nonzero on violation.
+``--smoke`` asserts the CI serving-job invariants (every request
+completed, zero leaked blocks) and exits nonzero on violation.
 """
 import argparse
 import hashlib
@@ -81,9 +82,6 @@ def run(args):
     wall = time.perf_counter() - t0
 
     tokens = sum(len(v) for v in engine.completed.values())
-    lat = np.array(sorted(engine.latency_steps.values()), np.float64)
-    p50 = float(np.percentile(lat, 50)) if len(lat) else float("nan")
-    p99 = float(np.percentile(lat, 99)) if len(lat) else float("nan")
     events = engine.scheduler.events
     preempts = sum(1 for e in events if e[0] == "preempt")
     leaked = engine.leaked_blocks()
@@ -95,8 +93,7 @@ def run(args):
         requests=args.requests, completed=len(engine.completed),
         steps=engine.step_count)
     row("serve/latency", wall / max(engine.step_count, 1),
-        f"p50={p50:.0f} p99={p99:.0f} steps",
-        p50_steps=p50, p99_steps=p99, preemptions=preempts,
+        f"{preempts} preemptions", preemptions=preempts,
         leaked_blocks=leaked, trace_sha256=thash,
         num_blocks=args.num_blocks, block_size=args.block_size,
         slots=args.slots)
@@ -109,7 +106,6 @@ def run(args):
     if args.smoke:
         assert len(engine.completed) > 0, "smoke: no requests completed"
         assert leaked == 0, f"smoke: {leaked} leaked blocks"
-        assert np.isfinite(p99), "smoke: p99 latency not finite"
         assert len(engine.completed) == args.requests, (
             f"smoke: only {len(engine.completed)}/{args.requests} finished"
         )

@@ -13,6 +13,8 @@ The package splits along the host/device boundary:
   - ``engine``      — the continuous-batching loop wiring the scheduler to
                       jitted paged prefill/decode steps (imports the model
                       stack; import it explicitly)
+  - ``tracing``     — the engine's named spans (``serve.<what>``) in the
+                      profiler's trace
 """
 from repro.serving.paged_cache import PagedKVCache, NULL_BLOCK
 from repro.serving.scheduler import (

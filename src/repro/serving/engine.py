@@ -34,6 +34,7 @@ import numpy as np
 
 from repro.serving import scheduler as sched
 from repro.serving.scheduler import NULL_BLOCK, Request
+from repro.serving.tracing import span, step_span
 
 __all__ = ["ServingEngine", "PagedModel", "StubModel", "Request"]
 
@@ -153,13 +154,13 @@ class PagedModel:
         ))
         self._attn_fn = attn_fn
 
-        def decode(p, c, b):
+        def serve_decode(p, c, b):
             logits, c = transformer.decode_step_paged(
                 p, cfg, c, b, attn_fn=attn_fn
             )
             return logits, self._place(c)
 
-        self._decode_jit = jax.jit(decode, donate_argnums=(1,))
+        self._decode_jit = jax.jit(serve_decode, donate_argnums=(1,))
         self._prefill_jit: dict[int, object] = {}  # per length bucket
         self.last_logits = None  # newest decode step's (max_slots, V_pad)
 
@@ -203,7 +204,7 @@ class PagedModel:
                                        in_specs=(P(), P()),
                                        out_specs=(P(), P()), check_vma=False)
 
-            def run(params, cache, tokens, block_ids, last_idx):
+            def serve_prefill(params, cache, tokens, block_ids, last_idx):
                 # tokens (1, sb) padded prompt; causal attention keeps every
                 # real row independent of the padded tail
                 logits, kv = prompt(params, tokens)
@@ -219,7 +220,8 @@ class PagedModel:
                 ).astype(jnp.int32)
                 return cache, first
 
-            self._prefill_jit[sb] = jax.jit(run, donate_argnums=(1,))
+            self._prefill_jit[sb] = jax.jit(serve_prefill,
+                                            donate_argnums=(1,))
         return self._prefill_jit[sb]
 
     def prefill(self, seq, block_ids):
@@ -231,13 +233,15 @@ class PagedModel:
         pages = self._pages(seq.slot, block_ids)
         ids = np.full(sb // self.block_size, NULL_BLOCK, np.int32)
         ids[: len(pages)] = pages  # prompt pages (grant covers them)
-        self.cache, first = self._prefill_fn(sb)(
-            self.params, self.cache, jnp.asarray(tokens), jnp.asarray(ids),
-            jnp.int32(len(prompt) - 1),
-        )
+        with span("prefill", rid=seq.rid, tokens=len(prompt)):
+            self.cache, first = self._prefill_fn(sb)(
+                self.params, self.cache, jnp.asarray(tokens),
+                jnp.asarray(ids), jnp.int32(len(prompt) - 1),
+            )
+            first = int(first)
         self.tables[seq.slot, :] = NULL_BLOCK
         self.tables[seq.slot, : len(pages)] = pages
-        return int(first)
+        return first
 
     # -- decode -------------------------------------------------------------
 
@@ -249,16 +253,21 @@ class PagedModel:
 
     def decode(self, slot_tokens, slot_positions, slot_tables, active):
         jnp = self._jnp
-        batch = {
-            "token": jnp.asarray(slot_tokens, jnp.int32),
-            "position": jnp.asarray(slot_positions, jnp.int32),
-            "block_table": jnp.asarray(slot_tables, jnp.int32),
-        }
-        logits, self.cache = self._decode_jit(self.params, self.cache, batch)
-        self.last_logits = logits
-        return np.asarray(
-            jnp.argmax(logits[:, : self.vocab], axis=-1)
-        ).astype(np.int64)
+        with span("decode", live=int(np.count_nonzero(active)),
+                  slots=len(active)):
+            with span("decode.launch"):
+                batch = {
+                    "token": jnp.asarray(slot_tokens, jnp.int32),
+                    "position": jnp.asarray(slot_positions, jnp.int32),
+                    "block_table": jnp.asarray(slot_tables, jnp.int32),
+                }
+                logits, self.cache = self._decode_jit(self.params,
+                                                      self.cache, batch)
+            self.last_logits = logits
+            with span("decode.sample"):
+                return np.asarray(
+                    jnp.argmax(logits[:, : self.vocab], axis=-1)
+                ).astype(np.int64)
 
     # -- preemption payloads -------------------------------------------------
 
@@ -292,7 +301,6 @@ class ServingEngine:
         self.eos_id = eos_id
         self.step_count = 0
         self.completed: dict[int, tuple] = {}  # rid -> generated tokens
-        self.latency_steps: dict[int, int] = {}  # rid -> retire - arrival
         # snapshot a victim's pages to host BEFORE the scheduler frees the
         # ledger entries (the resume half restores them bitwise)
         orig_preempt = self.scheduler.preempt
@@ -322,11 +330,17 @@ class ServingEngine:
     # -- one step of virtual time -------------------------------------------
 
     def step(self) -> int:
-        """Admissions + one decode over all slots. Returns the number of
-        live tokens produced this step."""
+        """Admissions + one decode over all slots, in a ``serve.step``
+        span. Returns the number of live tokens produced this step."""
+        sc = self.scheduler
+        queued = len(sc.pending) + sum(map(len, sc.queues.values()))
+        with step_span(self.step_count, running=len(sc.running),
+                       queued=queued):
+            return self._step()
+
+    def _step(self) -> int:
         s = self.step_count
         sc = self.scheduler
-
         for seq in sc.admit(s):
             if seq.saved_payload is not None:  # resume: restore pages
                 self.model.restore_blocks(seq, seq.blocks, seq.saved_payload)
@@ -386,7 +400,6 @@ class ServingEngine:
     def _retire(self, seq, step: int) -> None:
         self.scheduler.retire(seq, step)
         self.completed[seq.rid] = tuple(seq.generated)
-        self.latency_steps[seq.rid] = step - seq.req.arrival + 1
 
     def run(self, max_steps: int = 10_000) -> dict:
         while not self.scheduler.idle():

@@ -50,7 +50,7 @@ FLASH_SEQ = 256  # flash attention check length (one full prefill bucket)
 # and 28 layers of bf16 activations may grow that a few times more.
 KERNEL_TOL = 2e-2
 LOGITS_TOL = 5e-2
-MAIN_PATH = {"flash_attention": "pallas", "decode_attention": "xla"}
+MAIN_PATH = {"flash_attention": "pallas", "decode_attention": "pallas"}
 
 
 def check(ok: bool, what: str) -> None:

@@ -288,7 +288,8 @@ def decode_attention(q, k, v, position, *, window=0, scale=None,
                 "paged path; the contiguous path quantizes via precision="
             )
         paged_kwargs = {}
-        blocks = resolve_blocks("decode_attention", bs=bs)
+        # the contiguous scan's tile; ``pages`` is the paged kernel's own
+        blocks = {"bs": resolve_blocks("decode_attention", bs=bs)["bs"]}
     extra = {}
     # pos_offset may be a traced per-shard scalar; ride only when set so the
     # legacy kwarg surface (and its dispatch bytes) stay unchanged
@@ -314,8 +315,28 @@ def _decode_xla(q, k, v, position, *, window, scale, precision=None, bs=None,
     )
 
 
-# decode has no stream kernel yet: on TPU ``auto`` runs the blocked xla form
-# above, and ``pallas``/``interpret`` are not registered.
+@registry.register_stream_kernel("decode_attention")
+def _decode_stream(q, k, v, position, *, window, scale, precision=None,
+                   bs=None, block_table=None, k_scale=None, v_scale=None,
+                   pos_offset=0, return_lse=False, interpret=False):
+    """Paged calls stream the live pages through the block table
+    (``kernels/paged_attention.py``). A contiguous cache has no table to
+    stream through: those calls run the blocked xla form unchanged."""
+    if block_table is None:
+        return _decode_xla(
+            q, k, v, position, window=window, scale=scale,
+            precision=precision, bs=bs, pos_offset=pos_offset,
+            return_lse=return_lse,
+        )
+    from repro.kernels import paged_attention as _pa
+
+    return _pa.paged_decode_attention_pallas(
+        q, k, v, position, block_table, window=window, scale=scale,
+        precision=precision, k_scale=k_scale, v_scale=v_scale,
+        pos_offset=pos_offset, return_lse=return_lse, interpret=interpret,
+    )
+
+
 @registry.register_kernel("decode_attention", impl="ref")
 def _decode_ref(q, k, v, position, *, window, scale, precision=None, bs=None,
                 block_table=None, k_scale=None, v_scale=None, pos_offset=0,

@@ -148,7 +148,9 @@ _BLOCK_DEFAULTS: dict[str, dict[str, int]] = {
     "bsr_spmm": {"bf": 512},
     "spmspm": {"bm": 8, "bn": 128},
     "stencil": {"bx": 8},
-    "decode_attention": {"bs": 512},
+    # bs: the contiguous cache's scan tile; pages: table columns per grid
+    # step of the paged kernel (the pool's page extent pins its tile)
+    "decode_attention": {"bs": 512, "pages": 8},
 }
 _block_overrides: dict[str, dict[str, int]] = {}
 

@@ -476,8 +476,9 @@ def _stencil_case(rng) -> TuneCase:
 
 
 def _decode_attention_case(rng) -> TuneCase:
-    """decode has no StreamProgram (its blocked form is the xla impl), so
-    the VMEM probe uses a stream DESCRIPTION of that impl's cache traffic:
+    """the contiguous decode has no StreamProgram (its blocked form is the
+    xla impl; the Pallas kernel is paged only), so the VMEM probe uses a
+    stream DESCRIPTION of that impl's cache traffic:
     one resident q block plus double-buffered (bs x D) K/V cache tiles per
     grid step — the same footprint the online-softmax scan carries."""
     from repro.core.streams import AffineStream, StreamProgram

@@ -120,6 +120,14 @@ class PagedModel:
             (max_slots, max_blocks_per_seq), NULL_BLOCK, np.int32
         )
         attn_fn = None
+        if impl is not None:
+            from repro.kernels import ops
+
+            def attn_fn(q, kp, vp, ks, vs, tbl, pos, window):
+                return ops.decode_attention(
+                    q, kp, vp, pos, paged=True, block_table=tbl, k_scale=ks,
+                    v_scale=vs, window=window, impl=impl,
+                )
         pool_blocks = num_blocks
         self._pool_sharding = None
         if mesh is not None:
@@ -251,10 +259,23 @@ class PagedModel:
         self.tables[seq.slot, : len(seq.blocks)] = self._pages(seq.slot,
                                                                seq.blocks)
 
+    def live_pages(self, slot_positions) -> int:
+        """Table columns the decode attention reads this step, summed over
+        the rows: each row's pages up to its position (from the first page
+        of its lookback window, when the model has one)."""
+        pos = np.asarray(slot_positions)
+        last = pos // self.block_size
+        first = 0
+        if self.cfg.sliding_window:
+            first = np.maximum((pos - self.cfg.sliding_window + 1)
+                               // self.block_size, 0)
+        return int(np.sum(last - first + 1))
+
     def decode(self, slot_tokens, slot_positions, slot_tables, active):
         jnp = self._jnp
         with span("decode", live=int(np.count_nonzero(active)),
-                  slots=len(active)):
+                  slots=len(active), pages=self.live_pages(slot_positions),
+                  width=self.max_blocks_per_seq):
             with span("decode.launch"):
                 batch = {
                     "token": jnp.asarray(slot_tokens, jnp.int32),

@@ -12,7 +12,10 @@ beside the device's events (XProf's trace viewer, or
 - ``serve.prefill``, ``PagedModel.prefill``: ``rid``, ``tokens`` (the
   prompt's length);
 - ``serve.decode``, ``PagedModel.decode``: ``live`` (rows decoded),
-  ``slots`` (rows in the batch); inside it ``serve.decode.launch`` (the
+  ``slots`` (rows in the batch), ``pages`` (table columns the decode
+  attention reads, summed over the rows: ``position // block_size + 1``
+  each, fewer under a lookback window) and ``width`` (the table's columns
+  per row); inside it ``serve.decode.launch`` (the
   batch's upload and the program's dispatch) and ``serve.decode.sample``
   (the argmax over the logits and the tokens' read-back).
 

@@ -29,7 +29,7 @@ def traced(tmp_path_factory):
     for rid, (n, new, at) in enumerate(REQUESTS):
         eng.submit(Request(rid=rid, prompt=tuple(range(1, n + 1)),
                            max_new_tokens=new, arrival=at))
-    seen = {"step": [], "decode": []}
+    seen = {"step": [], "decode": [], "positions": []}
     step, decode, sc = eng.step, eng.model.decode, eng.scheduler
 
     def step_counted():
@@ -39,6 +39,7 @@ def traced(tmp_path_factory):
 
     def decode_counted(*a):
         seen["decode"].append(len(sc.running))
+        seen["positions"].append(list(a[1]))
         return decode(*a)
 
     eng.step, eng.model.decode = step_counted, decode_counted
@@ -97,6 +98,17 @@ def test_decode_span_counts_live_rows(traced):
     assert [d[3]["live"] for d in decodes] == seen["decode"]
     assert {d[3]["slots"] for d in decodes} == {SLOTS}
     assert len(set(seen["decode"])) > 1
+
+
+def test_decode_span_counts_the_pages_read(traced):
+    # every row, live or not, reads its pages up to its position
+    eng, seen, events = traced
+    bs = eng.model.block_size
+    decodes = _named(events, "serve.decode")
+    assert [d[3]["pages"] for d in decodes] == [
+        sum(p // bs + 1 for p in pos) for pos in seen["positions"]]
+    assert {d[3]["width"] for d in decodes} == {6}
+    assert max(d[3]["pages"] for d in decodes) > SLOTS
 
 
 def test_prefill_span_counts_the_admitted_prompt(traced):

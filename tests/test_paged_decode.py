@@ -8,6 +8,9 @@ S = NB * bs is cut into NB pages per sequence and scattered into a pool at
 arbitrary physical indices; the block table maps logical page j back to
 its physical slot. Pool page extent pins bs, so both paths stream
 identical (bs x D) tiles through the identical scan body.
+
+The Pallas paged kernel (run interpreted here) sums in another order, so it
+is checked against the paged oracle with allclose.
 """
 import json
 import os
@@ -130,6 +133,114 @@ def test_null_block_rows_are_exact_noops(rng):
     got = ops.decode_attention(q, kp, vp, pos_short, paged=True,
                                block_table=jnp.asarray(tbl_null))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# ---------------------------------------------------------------------------
+# The Pallas paged kernel (interpret mode) against the paged oracle. Its
+# summation order differs from the scan's, so the check is allclose.
+# ---------------------------------------------------------------------------
+
+
+def _kernel_vs_ref(q, kp, vp, pos, tbl, **kw):
+    got = ops.decode_attention(q, kp, vp, pos, paged=True, block_table=tbl,
+                               impl="interpret", **kw)
+    want = ops.decode_attention(q, kp, vp, pos, paged=True, block_table=tbl,
+                                impl="ref", **kw)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-5, atol=1e-5)
+
+
+# each row's position sits on the last row of a page, or one past it (the
+# first row of the next page, the only live token there)
+_BOUNDARY = {"on": [15, 31, 47], "past": [16, 32, 48]}
+
+
+@pytest.mark.parametrize("kv_heads", [8, 2], ids=["mha", "gqa"])
+@pytest.mark.parametrize("window", [0, 13])
+@pytest.mark.parametrize("where", sorted(_BOUNDARY))
+def test_pallas_paged_matches_ref(rng, kv_heads, window, where):
+    q, _, _, _, kp, vp, _, _, tbl = _paged_setup(rng, K=kv_heads)
+    pos = jnp.asarray(_BOUNDARY[where], jnp.int32)
+    _kernel_vs_ref(q, kp, vp, pos, tbl, window=window)
+
+
+@pytest.mark.parametrize("pages", [1, 3, 8])
+def test_pallas_paged_pages_per_step(rng, pages):
+    # 3 does not divide the table's 4 columns (the table is padded) and 8
+    # exceeds them (one step covers the row)
+    from repro.kernels import registry as kreg
+
+    q, _, _, pos, kp, vp, _, _, tbl = _paged_setup(rng)
+    with kreg.block_override("decode_attention", pages=pages):
+        _kernel_vs_ref(q, kp, vp, pos, tbl)
+
+
+def test_pallas_paged_inactive_row_on_scratch_page(rng):
+    # an inactive slot: position 0, its whole table row on scratch page 0
+    q, _, _, pos, kp, vp, _, _, tbl = _paged_setup(rng)
+    pos = pos.at[1].set(0)
+    tbl = tbl.at[1].set(NULL_BLOCK)
+    _kernel_vs_ref(q, kp, vp, pos, tbl)
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_pallas_paged_pos_offset_return_lse(rng, traced):
+    # a ring shard's table starts at pos_offset 32: row 0 (position 25) has
+    # no live column there and must come back as o = 0, lse = -1e30
+    q, _, _, _, kp, vp, _, _, tbl = _paged_setup(rng)
+    pos = jnp.asarray([25, 40, 63], jnp.int32)
+    if traced:
+        got = jax.jit(lambda off: ops.decode_attention(
+            q, kp, vp, pos, paged=True, block_table=tbl, impl="interpret",
+            pos_offset=off, return_lse=True))(jnp.int32(32))
+        want = ops.decode_attention(q, kp, vp, pos, paged=True,
+                                    block_table=tbl, impl="ref",
+                                    pos_offset=32, return_lse=True)
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=1e-5, atol=1e-5)
+    else:
+        _kernel_vs_ref(q, kp, vp, pos, tbl, pos_offset=32, return_lse=True)
+    o, lse = ops.decode_attention(q, kp, vp, pos, paged=True, block_table=tbl,
+                                  impl="interpret", pos_offset=32,
+                                  return_lse=True)
+    assert not np.asarray(o[0]).any() and np.all(np.asarray(lse[0]) <= -1e29)
+
+
+@pytest.mark.parametrize("policy", ["fp8", "prequant"])
+def test_pallas_paged_narrow_pool(rng, policy):
+    # quantize-at-use, and a pool held narrow: pages dequantize in-kernel
+    if policy == "prequant":
+        q, _, _, pos, kp, vp, ks, vs, tbl = _paged_setup(rng,
+                                                        policy="prequant")
+        _kernel_vs_ref(q, kp, vp, pos, tbl, k_scale=ks, v_scale=vs)
+    else:
+        q, _, _, pos, kp, vp, _, _, tbl = _paged_setup(rng)
+        _kernel_vs_ref(q, kp, vp, pos, tbl, precision=policy)
+
+
+def test_engine_serves_same_tokens_interpret_vs_xla():
+    # the engine's decode through the Pallas kernel (interpret) gives the
+    # greedy tokens of the xla form
+    from repro.configs.base import get_config
+    from repro.models import registry as mreg
+    from repro.serving.engine import Request, ServingEngine
+
+    cfg = get_config("occamy-gptj", reduced=True)
+    params = mreg.init_params(cfg, jax.random.PRNGKey(0))
+    prompts = [tuple(range(1 + i, 1 + i + n)) for i, n in enumerate((5, 18, 9))]
+
+    def serve(impl):
+        eng = ServingEngine.with_model(cfg, params, num_blocks=16,
+                                       block_size=4, max_slots=2,
+                                       max_blocks_per_seq=8, impl=impl)
+        for rid, p in enumerate(prompts):
+            eng.submit(Request(rid=rid, prompt=p, max_new_tokens=5))
+        return eng.run()
+
+    assert serve("interpret") == serve("xla")
 
 
 # ---------------------------------------------------------------------------
@@ -389,9 +500,12 @@ _ENGINE_RING_SUBPROCESS = textwrap.dedent(
     prompts = [tuple(rng.integers(0, cfg.vocab_size, n)) for n in
                (5, 19, 33, 47)]
     geo = dict(num_blocks=32, block_size=8, max_slots=4, max_blocks_per_seq=8)
+    RING_IMPL = None  # the decode attention impl of the ring's shards
 
     def serve(mesh):
-        eng = ServingEngine.with_model(cfg, params, mesh=mesh, **geo)
+        eng = ServingEngine.with_model(
+            cfg, params, mesh=mesh, impl=None if mesh is None else RING_IMPL,
+            **geo)
         for i, p in enumerate(prompts):
             eng.submit(Request(rid=i, prompt=p, max_new_tokens=6))
         return eng, eng.run()
@@ -410,14 +524,33 @@ _ENGINE_RING_SUBPROCESS = textwrap.dedent(
 )
 
 
-@pytest.mark.slow
-def test_engine_ring_decode_serves_same_tokens_4dev():
+def _run_engine_ring(ring_impl=None):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    proc = subprocess.run(
-        [sys.executable, "-c", _ENGINE_RING_SUBPROCESS],
+    script = _ENGINE_RING_SUBPROCESS.replace(
+        "RING_IMPL = None", f"RING_IMPL = {ring_impl!r}")
+    return subprocess.run(
+        [sys.executable, "-c", script],
         capture_output=True, text=True, env=env, timeout=900,
     )
+
+
+@pytest.mark.slow
+def test_engine_ring_decode_through_the_kernel_4dev():
+    # every shard runs the Pallas paged kernel (interpreted), with its
+    # traced pos_offset and the log-sum-exp the ring folds
+    proc = _run_engine_ring("interpret")
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    line = [ln for ln in proc.stdout.splitlines()
+            if ln.startswith("RESULT:")][-1]
+    out = json.loads(line[len("RESULT:"):])
+    assert out["same_tokens"], out
+    assert out["leaked"] == 0, out
+
+
+@pytest.mark.slow
+def test_engine_ring_decode_serves_same_tokens_4dev():
+    proc = _run_engine_ring()
     assert proc.returncode == 0, proc.stderr[-3000:]
     line = [ln for ln in proc.stdout.splitlines()
             if ln.startswith("RESULT:")][-1]

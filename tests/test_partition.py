@@ -538,8 +538,8 @@ _EQUIV = textwrap.dedent(
     w = np.array([0.2, 0.3, 0.4, 0.1], np.float32)
 
     # stream ops cover interpret/xla/ref (the pallas entry is the same
-    # StreamProgram, compiled); decode_attention has no stream kernel, so
-    # it covers auto/xla/ref
+    # StreamProgram, compiled); decode_attention's stream kernel is paged
+    # only (a contiguous cache runs the xla form), so it covers auto/xla/ref
     for impl in ("interpret", "xla", "ref"):
         check(f"gemm[{impl}]",
               ops.gemm(a, b, mesh=mesh, impl=impl, out_dtype=f32),

@@ -43,14 +43,18 @@ def test_register_rejects_auto():
 
 def test_all_ops_registered_with_all_impls():
     for op in ("gemm", "flash_attention", "linear_attention", "spmm",
-               "bsr_spmm", "spmspm", "stencil"):
+               "bsr_spmm", "spmspm", "stencil", "decode_attention"):
         assert registry.implementations(op) == [
             "interpret", "pallas", "ref", "xla"
         ], op
-    # no stream kernel yet: no name may stand in for one
-    assert registry.implementations("decode_attention") == ["ref", "xla"]
-    with pytest.raises(NotImplementedError, match="no 'pallas'"):
-        registry.kernel_call("decode_attention", impl="pallas")
+    # an op without a kernel has no pallas entry, and asking for one raises
+    registry.register_kernel("_test_xla_only", impl="xla")(lambda: "xla")
+    try:
+        assert registry.implementations("_test_xla_only") == ["xla"]
+        with pytest.raises(NotImplementedError, match="no 'pallas'"):
+            registry.kernel_call("_test_xla_only", impl="pallas")
+    finally:
+        registry._REGISTRY.pop("_test_xla_only")
 
 
 def test_auto_resolves_per_op_on_tpu(monkeypatch):
@@ -59,7 +63,12 @@ def test_auto_resolves_per_op_on_tpu(monkeypatch):
     monkeypatch.setattr(registry.jax, "default_backend", lambda: "tpu")
     assert registry.resolve_impl(None, "flash_attention") == "pallas"
     assert registry.resolve_impl(None, "gemm") == "pallas"
-    assert registry.resolve_impl(None, "decode_attention") == "xla"
+    assert registry.resolve_impl(None, "decode_attention") == "pallas"
+    registry.register_kernel("_test_xla_only", impl="xla")(lambda: "xla")
+    try:  # an op without a kernel runs its xla form
+        assert registry.resolve_impl(None, "_test_xla_only") == "xla"
+    finally:
+        registry._REGISTRY.pop("_test_xla_only")
     assert registry.resolve_impl("ref", "flash_attention") == "ref"
     monkeypatch.setattr(registry.jax, "default_backend", lambda: "cpu")
     assert registry.resolve_impl(None, "flash_attention") == "xla"
@@ -190,7 +199,15 @@ def _geometry_cases(rng):
     offs = np.array([(0, 0, 0), (1, 0, 0)], np.int32)
     w = np.array([0.5, 0.5], np.float32)
     T = len(bsrA.tile_rows)
+    dq = jnp.asarray(rng.standard_normal((2, 4, 8)), f32)
+    pool = jnp.asarray(rng.standard_normal((9, 4, 4, 8)), f32)
+    table = jnp.arange(1, 9, dtype=jnp.int32).reshape(2, 4)
+    dpos = jnp.asarray([9, 14], jnp.int32)
     return [
+        ("decode_attention", "repro.kernels.paged_attention", dict(pages=2),
+         (2, 2),
+         lambda: ops.decode_attention(dq, pool, pool, dpos, paged=True,
+                                      block_table=table, impl="interpret")),
         ("gemm", "repro.kernels.gemm", dict(bm=16, bk=32, bn=16), (4, 4, 2),
          lambda: ops.gemm(a, a, impl="interpret")),
         ("flash_attention", "repro.kernels.flash_attention",
@@ -211,11 +228,10 @@ def _geometry_cases(rng):
 
 def test_block_override_changes_geometry_for_every_op(rng, monkeypatch):
     cases = _geometry_cases(rng)
-    # decode_attention is xla-blocked, not stream-programmed: its override
-    # path is covered by test_partition.test_decode_attention_override_reaches_xla_impl
-    assert {c[0] for c in cases} == set(registry._BLOCK_DEFAULTS) - {
-        "decode_attention"
-    }
+    # decode_attention's case is its paged kernel (``pages``); the
+    # contiguous cache's ``bs`` reaches the xla form, covered by
+    # test_partition.test_decode_attention_override_reaches_xla_impl
+    assert {c[0] for c in cases} == set(registry._BLOCK_DEFAULTS)
     for op, module, override, want_grid, call in cases:
         registry.clear_block_overrides()
         registry.set_block_override(op, **override)

@@ -94,6 +94,21 @@ def test_paged_decode_attention_xla_engine_shapes(one_chip):
              ((8, 16), I32))
 
 
+@pytest.mark.parametrize("ring", [False, True], ids=["engine", "ring"])
+def test_paged_decode_attention_pallas_cell_geometry(one_chip, ring):
+    # the gptj-chat cell: 3 slots of 128 pages of 16, a pool of 304 pages;
+    # ring decode adds a traced pos_offset and the log-sum-exp
+    def f(q, kp, vp, pos, tbl, off):
+        extra = dict(pos_offset=off, return_lse=True) if ring else {}
+        return ops.decode_attention(q, kp, vp, pos, paged=True,
+                                    block_table=tbl, impl="pallas", **extra)
+
+    pool = ((304, 16, 16, 256), BF16)
+    text = _compile(f, one_chip, ((3, 16, 256), BF16), pool, pool,
+                    ((3,), I32), ((3, 128), I32), ((), I32))
+    assert "tpu_custom_call" in text
+
+
 def test_gptj_decode_step_paged_fits_one_chip(one_chip):
     from repro.configs.base import get_config
     from repro.models import registry, transformer

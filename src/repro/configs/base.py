@@ -38,6 +38,26 @@ class ModelConfig:
     experts_per_token: int = 0
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # softmax: top-k, tokens past each expert's capacity dropped (moe_mlp);
+    # sigmoid_bias (DeepSeek-V3 noaux_tc): the no-drop grouped layer
+    # (moe_grouped), the only one that reads the fields below
+    router: str = "softmax"
+    routed_scale: float = 1.0  # routed weights' factor after normalising
+    moe_d_ff: int = 0  # width of one routed expert; 0 => d_ff
+    num_shared_experts: int = 0  # one SwiGLU of num_shared * moe width
+    first_dense_layers: int = 0  # leading layers with a dense MLP of d_ff
+    # expert parallelism: this device holds experts [experts_first,
+    # experts_first + experts_held); 0 => all of them
+    experts_held: int = 0
+    experts_first: int = 0
+
+    # multi-head latent attention (MLA); on when kv_lora_rank > 0. Queries
+    # are projected whole (q_lora_rank null); keys and values come from a
+    # latent of kv_lora_rank plus one shared rotary key of qk_rope_head_dim
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # ssm / hybrid (rwkv6, hymba)
     ssm_state: int = 0
@@ -85,6 +105,39 @@ class ModelConfig:
     def resolved_d_inner(self) -> int:
         return self.d_inner if self.d_inner else 2 * self.d_model
 
+    def resolved_moe_d_ff(self) -> int:
+        return self.moe_d_ff if self.moe_d_ff else self.d_ff
+
+    @property
+    def grouped_moe(self) -> bool:
+        """The expert layer is ``moe.moe_grouped`` (sigmoid/bias router,
+        held experts, no capacity); else ``moe.moe_mlp``."""
+        return bool(self.num_experts) and self.router == "sigmoid_bias"
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def qk_head_dim(self) -> int:
+        """Width of each head's query and key: MLA's non-rotary plus
+        rotary part, else the head."""
+        if self.mla:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
+        return self.resolved_head_dim()
+
+    @property
+    def latent_dim(self) -> int:
+        """Values cached per token and layer under MLA: the latent and the
+        shared rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def held_experts(self) -> tuple[int, int]:
+        """(first, count) of the routed experts this device holds."""
+        n = self.experts_held or self.num_experts
+        return self.experts_first, n
+
     @property
     def q_per_kv(self) -> int:
         return self.num_heads // max(self.num_kv_heads, 1)
@@ -96,15 +149,29 @@ class ModelConfig:
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
 
+    def _attn_params(self) -> int:
+        d, hd = self.d_model, self.resolved_head_dim()
+        H, K = self.num_heads, self.num_kv_heads
+        if self.mla:
+            r = self.kv_lora_rank
+            return (d * H * self.qk_head_dim + d * self.latent_dim + r
+                    + r * H * (self.qk_nope_head_dim + self.v_head_dim)
+                    + H * self.v_head_dim * d)
+        return d * (H * hd) + 2 * d * (K * hd) + (H * hd) * d
+
     def num_params(self) -> int:
         """Analytic parameter count (used for MODEL_FLOPS = 6*N*D)."""
-        d, f, hd = self.d_model, self.d_ff, self.resolved_head_dim()
-        H, K = self.num_heads, self.num_kv_heads
+        d, f = self.d_model, self.d_ff
         gate_mult = 2 if self.activation in ("swiglu", "geglu") else 1
         ffn = d * f * gate_mult + f * d
         if self.num_experts:
-            ffn = ffn * self.num_experts + d * self.num_experts  # + router
-        attn = d * (H * hd) + 2 * d * (K * hd) + (H * hd) * d
+            fe = self.resolved_moe_d_ff()
+            expert = d * fe * gate_mult + fe * d
+            ffn = (expert * (self.num_experts + self.num_shared_experts)
+                   + d * self.num_experts)  # + router
+            if self.router == "sigmoid_bias":
+                ffn += self.num_experts  # the correction bias
+        attn = self._attn_params()
         if self.family == "ssm":
             attn = 0
         per_layer = attn + ffn + 2 * d
@@ -113,22 +180,26 @@ class ModelConfig:
             ssm = d * 2 * di + di * n * 2 + di + di * d  # in-proj, B/C, dt, out
             per_layer += ssm
         n_params = self.num_layers * per_layer + self.vocab_size * d
+        if self.first_dense_layers:
+            n_params += self.first_dense_layers * (
+                d * f * gate_mult + f * d - ffn)
         if not self.tie_embeddings:
             n_params += self.vocab_size * d
         if self.encoder_layers:
             n_params += self.encoder_layers * (attn + d * f * gate_mult + f * d + 2 * d)
-            n_params += self.num_layers * (d * (H * hd) + 2 * d * (K * hd) + (H * hd) * d + d)
+            n_params += self.num_layers * (self._attn_params() + d)
         return n_params
 
     def num_active_params(self) -> int:
         """Active parameters per token (MoE: only routed experts count)."""
         if not self.num_experts:
             return self.num_params()
-        d, f = self.d_model, self.d_ff
+        d, f = self.d_model, self.resolved_moe_d_ff()
         gate_mult = 2 if self.activation in ("swiglu", "geglu") else 1
         per_expert = d * f * gate_mult + f * d
         inactive = (self.num_experts - self.experts_per_token) * per_expert
-        return self.num_params() - self.num_layers * inactive
+        moe_layers = self.num_layers - self.first_dense_layers
+        return self.num_params() - moe_layers * inactive
 
 
 @dataclass(frozen=True)

@@ -103,7 +103,9 @@ class StreamProgram:
     ``index_args`` are scalar-prefetched (SMEM-resident) index arrays,
     available to every IndirectStream's index_map and to the body as leading
     refs. ``dimension_semantics`` annotates each grid axis as "parallel" or
-    "arbitrary" (sequential) for the TPU pipeliner.
+    "arbitrary" (sequential) for the TPU pipeliner. ``trace_name``, where
+    set, names the kernel's instruction in the compiled program, and so its
+    ops in the profiler's trace.
     """
 
     name: str
@@ -115,6 +117,7 @@ class StreamProgram:
     index_args: tuple = ()
     scratch: tuple = ()
     dimension_semantics: tuple | None = None
+    trace_name: str | None = None
 
     @property
     def steps(self) -> int:
@@ -221,6 +224,8 @@ def stream_compute(program: StreamProgram, *operands, interpret: bool = False):
         out_shapes = list(program.out_shapes)
 
     kwargs: dict = {"out_shape": out_shapes, "interpret": interpret}
+    if program.trace_name:
+        kwargs["name"] = program.trace_name
     if program.dimension_semantics is not None and not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=tuple(program.dimension_semantics)

@@ -145,28 +145,32 @@ def _fa_kernel(
 def flash_attention_program(
     B, H, G, Sqp, D, nq, nk, bq, bk, dtype, k_dtype, v_dtype,
     *, scale, causal, window, q_offset, sk, return_lse=False, scaled=False,
+    Dv=None,
 ) -> StreamProgram:
     """FA-2 as a stream program: q/o stream over (b, h, iq); the k/v streams
     revisit the shared KV head h//G — the GQA index map. ``return_lse``
     adds a second (B, H, Sqp) fp32 output stream carrying the per-row
     log-sum-exp (the ring-attention merge statistic). ``scaled`` adds
     three per-row fp32 scale streams (q, k, v) riding the same index maps
-    as their value streams — the quantized-operand path."""
+    as their value streams — the quantized-operand path. ``Dv`` (default
+    ``D``) is the width of v and o, narrower than q and k under latent
+    attention."""
+    Dv = Dv or D
     body = functools.partial(
         _fa_kernel, scale=scale, causal=causal, window=window,
         q_offset=q_offset, sk=sk, bq=bq, bk=bk, nk=nk, return_lse=return_lse,
         scaled=scaled,
     )
-    def kv_stream(dt):
+    def kv_stream(dt, width):
         return AffineStream(
-            (1, 1, bk, D), lambda b, h, i, j: (b, h // G, j, 0), dtype=dt
+            (1, 1, bk, width), lambda b, h, i, j: (b, h // G, j, 0), dtype=dt
         )
     in_streams = [
         AffineStream(
             (1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0), dtype=dtype
         ),
-        kv_stream(k_dtype),
-        kv_stream(v_dtype),
+        kv_stream(k_dtype, D),
+        kv_stream(v_dtype, Dv),
     ]
     if scaled:
         in_streams.append(AffineStream(
@@ -182,12 +186,12 @@ def flash_attention_program(
         )
     out_streams = [
         AffineStream(
-            (1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0),
+            (1, 1, bq, Dv), lambda b, h, i, j: (b, h, i, 0),
             dtype=jnp.float32 if scaled else dtype
         ),
     ]
     out_shapes = [jax.ShapeDtypeStruct(
-        (B, H, Sqp, D), jnp.float32 if scaled else dtype
+        (B, H, Sqp, Dv), jnp.float32 if scaled else dtype
     )]
     if return_lse:
         out_streams.append(AffineStream(
@@ -204,7 +208,7 @@ def flash_attention_program(
         scratch=(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
         ),
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
     )
@@ -213,7 +217,7 @@ def flash_attention_program(
 def flash_attention_pallas(
     q: jax.Array,  # (B, H, Sq, D)
     k: jax.Array,  # (B, K, Sk, D)
-    v: jax.Array,
+    v: jax.Array,  # (B, K, Sk, Dv), Dv <= D
     *,
     causal: bool = True,
     window: int = 0,
@@ -242,7 +246,7 @@ def flash_attention_pallas(
     program = flash_attention_program(
         B, H, G, Sq + pq, D, nq, nk, bq, bk, q.dtype, k.dtype, v.dtype,
         scale=scale, causal=causal, window=window, q_offset=q_offset, sk=Sk,
-        return_lse=return_lse,
+        return_lse=return_lse, Dv=v.shape[-1],
     )
     out = stream_compute(program, q, k, v, interpret=interpret)
     if return_lse:

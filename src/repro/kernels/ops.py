@@ -357,6 +357,54 @@ def _decode_ref(q, k, v, position, *, window, scale, precision=None, bs=None,
                                      return_lse=return_lse)
 
 
+def latent_decode_attention(q, pool, position, block_table, *, v_width,
+                            scale, impl=None):
+    """Absorbed latent attention (MLA) of one new token per sequence
+    against the serving engine's latent page pool.
+
+    ``q``: (B, H, D), each head's query in latent space followed by its
+    rotary part; ``pool``: (P, 1, bs, D) pages of latent rows (the latent,
+    then the shared rotary key); ``block_table``: (B, NB) int32 pool slots.
+    Every head attends over the rows' whole width, and the values are their
+    first ``v_width`` columns, so the pool is read once for both. Returns
+    (B, H, v_width). Its Pallas form is an instance of ``decode_attention``'s
+    paged kernel and takes that op's ``pages`` geometry; it has no block
+    table or partition rule of its own (one device only: the engine refuses
+    a mesh for a latent pool)."""
+    if pool.ndim != 4 or pool.shape[1] != 1 or pool.shape[3] != q.shape[-1]:
+        raise ValueError(
+            f"latent_decode_attention: pool must be (P, 1, bs, {q.shape[-1]})"
+            f", got {pool.shape}")
+    return kernel_call("latent_decode_attention", q, pool, position,
+                       block_table, v_width=v_width, scale=scale, impl=impl)
+
+
+@registry.register_stream_kernel("latent_decode_attention")
+def _latent_stream(q, pool, position, block_table, *, v_width, scale,
+                   interpret=False):
+    from repro.kernels import paged_attention as _pa
+
+    return _pa.latent_decode_attention_pallas(
+        q, pool, position, block_table, v_width=v_width, scale=scale,
+        interpret=interpret,
+    )
+
+
+@registry.register_kernel("latent_decode_attention", impl="xla")
+def _latent_xla(q, pool, position, block_table, *, v_width, scale):
+    return _xla.decode_attention_xla(
+        q, pool, pool[..., :v_width], position, scale=scale,
+        block_table=block_table,
+    )
+
+
+@registry.register_kernel("latent_decode_attention", impl="ref")
+def _latent_ref(q, pool, position, block_table, *, v_width, scale):
+    return _ref.decode_attention_paged_ref(
+        q, pool, pool[..., :v_width], block_table, position, scale=scale,
+    )
+
+
 # ---------------------------------------------------------------------------
 # Chunked linear attention with data-dependent decay (RWKV6 / SSD)
 # ---------------------------------------------------------------------------

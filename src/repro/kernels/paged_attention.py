@@ -20,6 +20,13 @@ two dots span ``pages * bs`` rows: one page at a time leaves the MXU
 mostly idle (16 rows a dot), and the kernel then runs several times
 slower than its DMA. The online softmax keeps ``(m, l, acc)`` in f32
 scratch across the row's steps and writes the output at its last step.
+
+Latent attention (MLA, absorbed) is the same program over a pool of one
+"KV head" whose rows are the latent and the shared rotary key
+(``latent_decode_attention_pallas``): every query head reads the one page
+tile, the keys are its whole width and the values its first ``v_width``
+columns, so one DMA per page serves both. That instance is named
+``mla_decode_attention`` in the compiled program and the profiler's trace.
 """
 from __future__ import annotations
 
@@ -57,11 +64,15 @@ def _live_bounds(position, *, bs: int, nb: int, window: int, pos_offset):
 
 
 def _paged_kernel(tbl_ref, first_ref, last_ref, lo_ref, hi_ref, q_ref, *refs,
-                  pages, bs, nsteps, scale, scaled, return_lse):
-    # refs: `pages` K page refs, `pages` V page refs, then (scaled) as many
-    # K and V scale refs; then o (and lse); then the (m, l, acc) scratch
-    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
-    refs = refs[2 * pages:]
+                  pages, bs, nsteps, scale, scaled, return_lse, shared_v=0):
+    # refs: `pages` K page refs, `pages` V page refs (none where the values
+    # are K's first `shared_v` columns), then (scaled) as many K and V
+    # scale refs; then o (and lse); then the (m, l, acc) scratch
+    k_refs = refs[:pages]
+    if shared_v:
+        v_refs, refs = None, refs[pages:]
+    else:
+        v_refs, refs = refs[pages:2 * pages], refs[2 * pages:]
     if scaled:
         ks_refs, vs_refs = refs[:pages], refs[pages:2 * pages]
         refs = refs[2 * pages:]
@@ -104,7 +115,10 @@ def _paged_kernel(tbl_ref, first_ref, last_ref, lo_ref, hi_ref, q_ref, *refs,
         p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
-        v = pages_of(v_refs, vs_refs if scaled else None)
+        if shared_v:
+            v = k[:, :, :shared_v]
+        else:
+            v = pages_of(v_refs, vs_refs if scaled else None)
         pv = jax.lax.dot_general(p.astype(v.dtype), v,
                                  (((2,), (1,)), ((0,), (0,))),
                                  preferred_element_type=jnp.float32)
@@ -121,7 +135,7 @@ def _paged_kernel(tbl_ref, first_ref, last_ref, lo_ref, hi_ref, q_ref, *refs,
 
 def paged_decode_program(B, K, G, D, bs, nbp, pages, dtype, pool_dtype,
                          index_args, *, scale, scaled=False,
-                         return_lse=False) -> StreamProgram:
+                         return_lse=False, shared_v=0) -> StreamProgram:
     """The paged decode as a stream program over grid ``(B, nbp / pages)``.
 
     ``index_args`` are the scalar-prefetched ``(table, first, last, lo,
@@ -129,8 +143,11 @@ def paged_decode_program(B, K, G, D, bs, nbp, pages, dtype, pool_dtype,
     (``_live_bounds``). Page stream ``i`` of step ``j`` reads table column
     ``j * pages + i``, clamped into the row's live columns ``[lo, hi]``.
     ``scaled`` binds a per-row fp32 scale stream beside every K and V page
-    stream."""
+    stream. ``shared_v`` > 0 binds no V streams: the values are the first
+    ``shared_v`` columns of the K pages (latent attention), and the output
+    is that wide."""
     nsteps = nbp // pages
+    Dv = shared_v or D
 
     def page_stream(i, block, dt):
         def index_map(b, j, tbl, first, last, lo, hi):
@@ -145,23 +162,26 @@ def paged_decode_program(B, K, G, D, bs, nbp, pages, dtype, pool_dtype,
     in_streams = [AffineStream((1, K, G, D), lambda b, j: (b, 0, 0, 0),
                                dtype=dtype)]
     in_streams += streams((1, K, bs, D), pool_dtype)  # K pages
-    in_streams += streams((1, K, bs, D), pool_dtype)  # V pages
+    if not shared_v:
+        in_streams += streams((1, K, bs, D), pool_dtype)  # V pages
     if scaled:
         in_streams += streams((1, K, bs, 1), jnp.float32)
         in_streams += streams((1, K, bs, 1), jnp.float32)
-    out_streams = [AffineStream((1, K, G, D), lambda b, j: (b, 0, 0, 0),
+    out_streams = [AffineStream((1, K, G, Dv), lambda b, j: (b, 0, 0, 0),
                                 dtype=dtype)]
-    out_shapes = [jax.ShapeDtypeStruct((B, K, G, D), dtype)]
+    out_shapes = [jax.ShapeDtypeStruct((B, K, G, Dv), dtype)]
     if return_lse:
         out_streams.append(AffineStream(
             (1, K, G, 1), lambda b, j: (b, 0, 0, 0), dtype=jnp.float32))
         out_shapes.append(jax.ShapeDtypeStruct((B, K, G, 1), jnp.float32))
     body = functools.partial(
         _paged_kernel, pages=pages, bs=bs, nsteps=nsteps, scale=scale,
-        scaled=scaled, return_lse=return_lse,
+        scaled=scaled, return_lse=return_lse, shared_v=shared_v,
     )
+    name = "mla_decode_attention" if shared_v else "paged_decode_attention"
     return StreamProgram(
-        name="paged_decode_attention",
+        name=name,
+        trace_name=name if shared_v else None,
         body=body,
         grid=(B, nsteps),
         in_streams=tuple(in_streams),
@@ -171,7 +191,7 @@ def paged_decode_program(B, K, G, D, bs, nbp, pages, dtype, pool_dtype,
         scratch=(
             pltpu.VMEM((K, G, 1), jnp.float32),
             pltpu.VMEM((K, G, 1), jnp.float32),
-            pltpu.VMEM((K, G, D), jnp.float32),
+            pltpu.VMEM((K, G, Dv), jnp.float32),
         ),
         dimension_semantics=("parallel", "arbitrary"),
     )
@@ -228,3 +248,31 @@ def paged_decode_attention_pallas(
         return out.reshape(B, H, D)
     o, lse = out
     return o.reshape(B, H, D), lse.reshape(B, H)
+
+
+def latent_decode_attention_pallas(
+    q: jax.Array,  # (B, H, D): the absorbed query, latent then rotary part
+    pool: jax.Array,  # (P, 1, bs, D) page pool of latent rows
+    position: jax.Array,  # (B,)
+    block_table: jax.Array,  # (B, NB) int32 pool slots
+    *,
+    v_width: int,
+    scale: float,
+    interpret: bool = False,
+):
+    """Absorbed latent attention of one new token per sequence, reading
+    only live pages: scores over each row's whole width, values its first
+    ``v_width`` columns. Returns (B, H, v_width)."""
+    B, H, D = q.shape
+    bs, nb = pool.shape[2], block_table.shape[1]
+    pages = min(resolve_blocks("decode_attention")["pages"], nb)
+    nbp = nb + (-nb) % pages
+    table = jnp.pad(block_table.astype(jnp.int32), ((0, 0), (0, nbp - nb)))
+    bounds = _live_bounds(position, bs=bs, nb=nb, window=0, pos_offset=0)
+    program = paged_decode_program(
+        B, 1, H, D, bs, nbp, pages, q.dtype, pool.dtype,
+        (table.reshape(-1), *bounds), scale=scale, shared_v=v_width,
+    )
+    out = stream_compute(program, q.reshape(B, 1, H, D), *[pool] * pages,
+                         interpret=interpret)
+    return out.reshape(B, H, v_width)

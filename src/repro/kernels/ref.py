@@ -99,7 +99,7 @@ def mha_ref(
     p = jax.nn.softmax(s, axis=-1)
     p = jnp.where(jnp.isnan(p), 0.0, p)  # fully-masked rows
     o = jnp.einsum("bkgqs,bksd->bkgqd", p, v.astype(jnp.float32))
-    o = o.reshape(B, H, Sq, D).astype(q.dtype)
+    o = o.reshape(B, H, Sq, v.shape[-1]).astype(q.dtype)
     if not return_lse:
         return o
     lse = jax.scipy.special.logsumexp(s, axis=-1)  # -inf on fully-masked rows
@@ -136,7 +136,7 @@ def decode_attention_ref(
     p = jax.nn.softmax(s, axis=-1)
     p = jnp.where(jnp.isnan(p), 0.0, p)  # fully-masked rows (empty shards)
     o = jnp.einsum("bkgs,bksd->bkgd", p, v.astype(jnp.float32))
-    o = o.reshape(B, H, D).astype(q.dtype)
+    o = o.reshape(B, H, v.shape[-1]).astype(q.dtype)
     if not return_lse:
         return o
     lse = jax.scipy.special.logsumexp(s, axis=-1)
@@ -181,9 +181,10 @@ def decode_attention_paged_ref(
         k = prec.dequantize_blockwise(k, k_scale, axis=-1)
         v = prec.dequantize_blockwise(v, v_scale, axis=-1)
     B, nb = block_table.shape
-    K, bs, D = k.shape[1], k.shape[2], k.shape[3]
+    K, bs = k.shape[1], k.shape[2]
     def gather(pool):
-        return jnp.moveaxis(pool[block_table], 1, 2).reshape(B, K, nb * bs, D)
+        return jnp.moveaxis(pool[block_table], 1, 2).reshape(
+            B, K, nb * bs, pool.shape[-1])
     return decode_attention_ref(
         q, gather(k), gather(v), position, window=window, scale=scale,
         pos_offset=pos_offset, return_lse=return_lse,

@@ -80,9 +80,11 @@ def flash_attention_xla(q, k, v, *, causal=True, window=0, q_offset=0,
     ``window`` bounds attention to ``(q_pos - window, q_pos]`` regardless
     of ``causal`` (the shared window semantics — see ``ref.mha_ref``).
     ``return_lse=True`` also returns the (B, H, Sq) fp32 log-sum-exp the
-    ring-attention merge consumes.
+    ring-attention merge consumes. ``v`` may be narrower than ``q``/``k``
+    (latent attention's value heads): the output takes ``v``'s width.
     """
     B, H, Sq, D = q.shape
+    Dv = v.shape[-1]
     K, Sk = k.shape[1], k.shape[2]
     G = H // K
     scale = scale if scale is not None else 1.0 / np.sqrt(D)
@@ -103,7 +105,7 @@ def flash_attention_xla(q, k, v, *, causal=True, window=0, q_offset=0,
 
     qf = (q.astype(jnp.float32) * scale).reshape(B, K, G, Sq, D)
     kb = jnp.moveaxis(k.reshape(B, K, nb, block_k, D), 2, 0)
-    vb = jnp.moveaxis(v.reshape(B, K, nb, block_k, D), 2, 0)
+    vb = jnp.moveaxis(v.reshape(B, K, nb, block_k, Dv), 2, 0)
     q_pos = jnp.arange(Sq) + q_offset  # absolute positions
 
     NEG = jnp.float32(-1e30)
@@ -131,12 +133,12 @@ def flash_attention_xla(q, k, v, *, causal=True, window=0, q_offset=0,
 
     m0 = jnp.full((B, K, G, Sq), NEG)
     l0 = jnp.zeros((B, K, G, Sq))
-    acc0 = jnp.zeros((B, K, G, Sq, D))
+    acc0 = jnp.zeros((B, K, G, Sq, Dv))
     (m, denom, acc), _ = jax.lax.scan(
         body, (m0, l0, acc0), (kb, vb, jnp.arange(nb))
     )
     o = acc / jnp.maximum(denom, 1e-30)[..., None]
-    o = o.reshape(B, H, Sq, D).astype(q.dtype)
+    o = o.reshape(B, H, Sq, Dv).astype(q.dtype)
     if not return_lse:
         return o
     lse = (m + jnp.log(jnp.maximum(denom, 1e-30))).reshape(B, H, Sq)
@@ -146,6 +148,7 @@ def flash_attention_xla(q, k, v, *, causal=True, window=0, q_offset=0,
 def _flash_attention_xla_unrolled(q, k, v, *, causal, window, q_offset, scale,
                                   bq=None, bk=None, return_lse=False):
     B, H, Sq, D = q.shape
+    Dv = v.shape[-1]
     K, Sk = k.shape[1], k.shape[2]
     G = H // K
     NEG = jnp.float32(-1e30)
@@ -168,7 +171,7 @@ def _flash_attention_xla_unrolled(q, k, v, *, causal, window, q_offset, scale,
         q_lo, q_hi = q_offset + i * bq, q_offset + (i + 1) * bq - 1
         m = jnp.full((B, K, G, bq), NEG)
         denom = jnp.zeros((B, K, G, bq))
-        acc = jnp.zeros((B, K, G, bq, D))
+        acc = jnp.zeros((B, K, G, bq, Dv))
         for j in range(nk):
             k_lo, k_hi = j * bk, (j + 1) * bk - 1
             if (causal or window) and k_lo > q_hi:
@@ -194,7 +197,7 @@ def _flash_attention_xla_unrolled(q, k, v, *, causal, window, q_offset, scale,
             m = m_new
         outs.append(acc / jnp.maximum(denom, 1e-30)[..., None])
         lses.append(m + jnp.log(jnp.maximum(denom, 1e-30)))
-    o = jnp.concatenate(outs, axis=3).reshape(B, H, Sq + pq, D)[:, :, :Sq]
+    o = jnp.concatenate(outs, axis=3).reshape(B, H, Sq + pq, Dv)[:, :, :Sq]
     o = o.astype(q.dtype)
     if not return_lse:
         return o
@@ -258,6 +261,7 @@ def decode_attention_xla(q, k, v, position, *, window=0, scale=None, bs=None,
     fp32 log-sum-exp the per-shard online-softmax merge consumes.
     """
     B, H, D = q.shape
+    Dv = v.shape[-1]
     K = k.shape[1]
     G = H // K
     scale = scale if scale is not None else 1.0 / np.sqrt(D)
@@ -291,7 +295,7 @@ def decode_attention_xla(q, k, v, position, *, window=0, scale=None, bs=None,
         def blk(x, d):
             return jnp.moveaxis(x.reshape(B, K, nb, bs, d), 2, 0)
 
-        kb, vb = blk(k, D), blk(v, D)
+        kb, vb = blk(k, D), blk(v, Dv)
         ksb = blk(k_scale, 1) if k_scale is not None else jnp.zeros((nb,))
         vsb = blk(v_scale, 1) if v_scale is not None else jnp.zeros((nb,))
     qf = (q.astype(jnp.float32) * scale).reshape(B, K, G, D)
@@ -323,7 +327,7 @@ def decode_attention_xla(q, k, v, position, *, window=0, scale=None, bs=None,
 
     m0 = jnp.full((B, K, G), NEG)
     l0 = jnp.zeros((B, K, G))
-    acc0 = jnp.zeros((B, K, G, D))
+    acc0 = jnp.zeros((B, K, G, Dv))
     if registry.unroll_inner_enabled() and not paged:
         carry = (m0, l0, acc0)
         for i in range(nb):
@@ -336,7 +340,7 @@ def decode_attention_xla(q, k, v, position, *, window=0, scale=None, bs=None,
             body, (m0, l0, acc0), (kb, vb, ksb, vsb, jnp.arange(nb))
         )
     o = acc / jnp.maximum(denom, 1e-30)[..., None]
-    o = o.reshape(B, H, D).astype(q.dtype)
+    o = o.reshape(B, H, Dv).astype(q.dtype)
     if not return_lse:
         return o
     lse = (m + jnp.log(jnp.maximum(denom, 1e-30))).reshape(B, H)

@@ -94,8 +94,9 @@ def generate() -> str:
     lines.append("|---|---|---|---|")
     for op in registry.registered_ops():
         impls = ", ".join(registry.implementations(op))
-        blocks = registry.resolve_blocks(op)
+        blocks = registry.block_defaults(op)
         blocks_s = ", ".join(f"{k}={v}" for k, v in sorted(blocks.items()))
+        blocks_s = blocks_s or "—"
         precs = ", ".join(precision.supported_policies(op))
         lines.append(f"| `{op}` | {impls} | {blocks_s} | {precs} |")
     lines.append("")

@@ -1,4 +1,5 @@
-"""Mixture-of-Experts layer with sorted, capacity-bounded dispatch.
+"""Mixture-of-Experts layers: sorted, capacity-bounded dispatch, and a
+no-drop grouped layer for an expert-parallel share.
 
 The top-k routing indirection is the LM-family instance of the paper's C2
 (indirect streams): runtime indices drive a gather -> dense compute -> scatter
@@ -11,6 +12,16 @@ Experts are TP-sharded on d_ff over the `model` axis (all experts resident on
 every model-group, like the paper's group-replicated left matrices); an
 all-to-all expert-parallel variant lives in parallel/collectives.py and is
 exercised in the §Perf hillclimb.
+
+``moe_grouped``, which the sigmoid/bias router selects
+(``router="sigmoid_bias"``, ``cfg.grouped_moe``), is the serving layer of a
+device that holds a contiguous range of the routed experts
+(``cfg.held_experts``): the router scores all E experts, every token's
+top-k rows that fall on a held expert are sorted by expert and computed by
+a grouped matmul (``jax.lax.ragged_dot``) with no capacity, so no token is
+dropped; rows routed to experts held elsewhere are left out, and the shared
+experts (a dense SwiGLU) are added for every token. Summed over the
+devices of an expert-parallel group, the routed parts give the whole layer.
 """
 from __future__ import annotations
 
@@ -26,7 +37,20 @@ from repro.models import layers as L
 from repro.parallel.sharding import constrain, current_mesh, dp_axes
 
 
+# the grouped layer's fields, which the capacity layer does not read
+GROUPED_ONLY = ("routed_scale", "moe_d_ff", "num_shared_experts",
+                "experts_held", "experts_first")
+
+
 def init_moe_params(kg, cfg, num_layers: int, dtype) -> dict:
+    if cfg.router != "softmax":
+        raise ValueError(f"unknown router {cfg.router!r}")
+    kept = [f for f in GROUPED_ONLY
+            if getattr(cfg, f) != type(cfg).__dataclass_fields__[f].default]
+    if kept:
+        raise ValueError(f"{cfg.name}: the softmax router's capacity layer "
+                         f"does not read {kept}; they need "
+                         "router='sigmoid_bias'")
     d, f, E = cfg.d_model, cfg.d_ff, cfg.num_experts
     fm = 2 if L.is_gated(cfg.activation) else 1
     p = {
@@ -207,3 +231,98 @@ def moe_mlp_decode(p, x, cfg):
         "bef,efd->bed", h, p["moe_wo"], preferred_element_type=jnp.float32
     )
     return jnp.einsum("bed,be->bd", y, w).astype(x.dtype), jnp.float32(0.0)
+
+
+# ---------------------------------------------------------------------------
+# grouped, no-drop dispatch over the held experts (serving)
+# ---------------------------------------------------------------------------
+
+
+def init_grouped_moe_params(kg, cfg, num_layers: int, dtype) -> dict:
+    """The grouped layer's leaves, stacked over ``num_layers``: the router
+    over all E experts (and its correction bias), the held experts only,
+    and the shared experts as one SwiGLU of ``num_shared * moe_d_ff``."""
+    d, f, E = cfg.d_model, cfg.resolved_moe_d_ff(), cfg.num_experts
+    _, n = cfg.held_experts
+    fs = cfg.num_shared_experts * f
+    p = {
+        "router": L.dense_init(kg(), (num_layers, d, E), dtype=dtype),
+        "moe_wi": L.dense_init(kg(), (num_layers, n, d, f), dtype=dtype),
+        "moe_wg": L.dense_init(kg(), (num_layers, n, d, f), dtype=dtype),
+        "moe_wo": L.dense_init(
+            kg(), (num_layers, n, f, d), scale=1.0 / math.sqrt(f), dtype=dtype
+        ),
+        "router_bias": L.dense_init(kg(), (num_layers, E), scale=0.05,
+                                    dtype=dtype),
+    }
+    if fs:
+        p["shared_wi"] = L.dense_init(kg(), (num_layers, d, fs), dtype=dtype)
+        p["shared_wg"] = L.dense_init(kg(), (num_layers, d, fs), dtype=dtype)
+        p["shared_wo"] = L.dense_init(
+            kg(), (num_layers, fs, d), scale=1.0 / math.sqrt(fs), dtype=dtype
+        )
+    return p
+
+
+def route_topk(p, x, cfg):
+    """(weights (N, k) f32, expert ids (N, k)) over all E experts, in f32,
+    by DeepSeek-V3's ``noaux_tc`` rule with one group: scores
+    ``s = sigmoid(x W_r)``, the top k chosen on ``s + b``, weighted by ``s``
+    at the chosen experts over their sum, times ``routed_scale``."""
+    k = cfg.experts_per_token
+    logits = jnp.einsum("nd,de->ne", x.astype(jnp.float32),
+                        p["router"].astype(jnp.float32))
+    s = jax.nn.sigmoid(logits)
+    _, topi = jax.lax.top_k(s + p["router_bias"].astype(jnp.float32), k)
+    w = jnp.take_along_axis(s, topi, axis=-1)
+    return w / w.sum(-1, keepdims=True) * cfg.routed_scale, topi
+
+
+def held_experts(p, x, w, topi, cfg, rows=None):
+    """The held experts' part of the routed output, (N, d) f32, and the
+    rows each held expert received, (n,) int32.
+
+    The (token, choice) pairs that fall on a held expert, and whose token
+    is one of ``rows`` (a (N,) bool mask; all when None), are sorted by
+    expert and computed by grouped matmuls; every other pair goes to a
+    sink group past the held ones, which no matmul reads, and adds 0."""
+    first, n = cfg.held_experts
+    N, k = topi.shape
+    act = L.activation_fn(cfg.activation)
+    local = topi - first
+    take = (local >= 0) & (local < n)
+    if rows is not None:
+        take = take & rows[:, None]
+    e = jnp.where(take, local, n).reshape(-1)
+    order = jnp.argsort(e, stable=True)
+    sizes = jnp.zeros((n + 1,), jnp.int32).at[e].add(1)[:n]
+    xs = x[order // k]  # (N*k, d), grouped by expert
+    h = jax.lax.ragged_dot(xs, p["moe_wi"], sizes,
+                           preferred_element_type=jnp.float32)
+    g = jax.lax.ragged_dot(xs, p["moe_wg"], sizes,
+                           preferred_element_type=jnp.float32)
+    u = (act(g) * h).astype(x.dtype)
+    y = jax.lax.ragged_dot(u, p["moe_wo"], sizes,
+                           preferred_element_type=jnp.float32)
+    # rows past the held groups belong to no group: what a grouped matmul
+    # leaves there is not read
+    live = jnp.arange(N * k) < sizes.sum()
+    y = jnp.where(live[:, None], y * w.reshape(-1)[order][:, None], 0.0)
+    inv = jnp.zeros((N * k,), jnp.int32).at[order].set(
+        jnp.arange(N * k, dtype=jnp.int32))
+    return y[inv].reshape(N, k, -1).sum(1), sizes
+
+
+def moe_grouped(p, x, cfg, rows=None):
+    """x: (N, d) -> (out (N, d), rows per held expert (n,) int32): the
+    routed part of the held experts plus the shared experts."""
+    with jax.named_scope("moe_route"):
+        w, topi = route_topk(p, x, cfg)
+    with jax.named_scope("moe_experts"):
+        routed, sizes = held_experts(p, x, w, topi, cfg, rows)
+    if "shared_wi" in p:
+        with jax.named_scope("moe_shared"):
+            shared = L.mlp({"wi": p["shared_wi"], "wg": p["shared_wg"],
+                            "wo": p["shared_wo"]}, x[None], cfg.activation)[0]
+        routed = routed + shared.astype(jnp.float32)
+    return routed.astype(x.dtype), sizes

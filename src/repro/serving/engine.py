@@ -24,7 +24,9 @@ The model half sits behind a tiny protocol (``prefill``/``decode``/
 a deterministic host-only stub (``StubModel``) with no compilation, while
 ``PagedModel`` is the real thing — optionally holding the cache fp8 via
 ``precision=`` and distributing decode attention with ``ring_decode`` over
-a mesh's ``data`` axis.
+a mesh's ``data`` axis. A model with latent attention (MLA) keeps a latent
+page pool instead of per-head K/V (``serving/paged_cache.py``), on one
+device and in the model's dtype.
 """
 from __future__ import annotations
 
@@ -35,6 +37,8 @@ import numpy as np
 from repro.serving import scheduler as sched
 from repro.serving.scheduler import NULL_BLOCK, Request
 from repro.serving.tracing import span, step_span
+
+PREFILL_BUCKET = 128  # prompts are padded to a multiple of this many tokens
 
 __all__ = ["ServingEngine", "PagedModel", "StubModel", "Request"]
 
@@ -91,6 +95,14 @@ class PagedModel:
     ``(j // NB_l) * shard_pages + 1 + s * NB_l + j % NB_l``. The weights are
     placed replicated and the pools sharded on their page axis once, here,
     and every step keeps them there.
+
+    A prompt is prefilled padded to a multiple of ``PREFILL_BUCKET`` tokens
+    (and of the page size), so that a mix of lengths compiles a few prefill
+    programs; the padding's pages past the grant are the scratch page.
+    Under a grouped MoE the decode program also returns how many (layer,
+    held expert) pairs computed a row, read back with the sampled tokens
+    (``serve.decode.experts``), and each prefill the rows its held experts
+    computed (``serve.prefill.experts``).
     """
 
     def __init__(self, cfg, params, *, num_blocks, block_size, max_slots,
@@ -107,6 +119,11 @@ class PagedModel:
                 f"PagedModel serves the transformer families (dense/moe), "
                 f"got {cfg.family!r}"
             )
+        if cfg.mla and (mesh is not None or precision is not None):
+            raise NotImplementedError(
+                f"{cfg.name}: the latent page pool is served on one device "
+                f"in the model's dtype; got mesh={mesh}, "
+                f"precision={precision}")
         self._jax, self._jnp = jax, jnp
         self._transformer = transformer
         self.cfg, self.params = cfg, params
@@ -119,8 +136,16 @@ class PagedModel:
         self.tables = np.full(
             (max_slots, max_blocks_per_seq), NULL_BLOCK, np.int32
         )
+        self._stats = cfg.grouped_moe
         attn_fn = None
-        if impl is not None:
+        if impl is not None and cfg.mla:
+            import functools
+
+            from repro.kernels import ops
+
+            attn_fn = functools.partial(ops.latent_decode_attention,
+                                        impl=impl)
+        elif impl is not None:
             from repro.kernels import ops
 
             def attn_fn(q, kp, vp, ks, vs, tbl, pos, window):
@@ -163,6 +188,10 @@ class PagedModel:
         self._attn_fn = attn_fn
 
         def serve_decode(p, c, b):
+            if self._stats:
+                logits, c, stats = transformer.decode_step_paged(
+                    p, cfg, c, b, attn_fn=attn_fn, stats=True)
+                return logits, self._place(c), stats["experts_hit"]
             logits, c = transformer.decode_step_paged(
                 p, cfg, c, b, attn_fn=attn_fn
             )
@@ -191,7 +220,8 @@ class PagedModel:
     # -- prefill ------------------------------------------------------------
 
     def _bucket(self, s0: int) -> int:
-        return self.block_size * math.ceil(s0 / self.block_size)
+        step = math.lcm(PREFILL_BUCKET, self.block_size)
+        return step * math.ceil(s0 / step)
 
     def _prefill_fn(self, sb: int):
         jax, jnp = self._jax, self._jnp
@@ -199,7 +229,11 @@ class PagedModel:
         if sb not in self._prefill_jit:
             nbp = sb // self.block_size
 
-            def prompt(params, tokens):
+            def prompt(params, tokens, length):
+                if self._stats:
+                    return tr.prefill_step(
+                        params, cfg, {"tokens": tokens, "length": length},
+                        max_len=sb, stats=True)
                 return tr.prefill_step(params, cfg, {"tokens": tokens},
                                        max_len=sb)
 
@@ -208,24 +242,38 @@ class PagedModel:
                 # never partitioned by the compiler, only inside shard_map
                 from jax.sharding import PartitionSpec as P
 
-                prompt = jax.shard_map(prompt, mesh=self.mesh,
-                                       in_specs=(P(), P()),
-                                       out_specs=(P(), P()), check_vma=False)
+                prompt = jax.shard_map(
+                    prompt, mesh=self.mesh, in_specs=(P(), P(), P()),
+                    out_specs=(P(),) * (3 if self._stats else 2),
+                    check_vma=False)
+
+            def latent_rows(x):  # (n, 1, sb, D) -> (n, nbp, 1, bs, D)
+                return x.reshape(x.shape[0], nbp, 1, self.block_size,
+                                 x.shape[-1])
 
             def serve_prefill(params, cache, tokens, block_ids, last_idx):
                 # tokens (1, sb) padded prompt; causal attention keeps every
                 # real row independent of the padded tail
-                logits, kv = prompt(params, tokens)
-                nl, _, K, _, hd = kv["k"].shape
-                def rows(x):  # (nl, nbp, K, bs, hd)
-                    return jnp.moveaxis(
-                        x[:, 0].reshape(nl, K, nbp, self.block_size, hd), 2, 1
-                    )
-                cache = self._place(cache.write_prompt(
-                    block_ids, rows(kv["k"]), rows(kv["v"])))
+                logits, kv, *stats = prompt(params, tokens, last_idx + 1)
+                if cfg.mla:
+                    cache = self._place(cache.write_prompt(
+                        block_ids, latent_rows(kv["latent"]),
+                        lead_rows=(latent_rows(kv["lead"]) if "lead" in kv
+                                   else None)))
+                else:
+                    nl, _, K, _, hd = kv["k"].shape
+                    def rows(x):  # (nl, nbp, K, bs, hd)
+                        return jnp.moveaxis(
+                            x[:, 0].reshape(nl, K, nbp, self.block_size, hd),
+                            2, 1
+                        )
+                    cache = self._place(cache.write_prompt(
+                        block_ids, rows(kv["k"]), rows(kv["v"])))
                 first = jnp.argmax(
                     logits[0, last_idx, : cfg.vocab_size]
                 ).astype(jnp.int32)
+                if stats:
+                    return cache, first, stats[0]["held_rows"]
                 return cache, first
 
             self._prefill_jit[sb] = jax.jit(serve_prefill,
@@ -242,10 +290,14 @@ class PagedModel:
         ids = np.full(sb // self.block_size, NULL_BLOCK, np.int32)
         ids[: len(pages)] = pages  # prompt pages (grant covers them)
         with span("prefill", rid=seq.rid, tokens=len(prompt)):
-            self.cache, first = self._prefill_fn(sb)(
+            self.cache, first, *held = self._prefill_fn(sb)(
                 self.params, self.cache, jnp.asarray(tokens),
                 jnp.asarray(ids), jnp.int32(len(prompt) - 1),
             )
+            if held:
+                first, held = self._jax.device_get((first, held[0]))
+                with span("prefill.experts", held_rows=int(held)):
+                    pass
             first = int(first)
         self.tables[seq.slot, :] = NULL_BLOCK
         self.tables[seq.slot, : len(pages)] = pages
@@ -282,9 +334,16 @@ class PagedModel:
                     "position": jnp.asarray(slot_positions, jnp.int32),
                     "block_table": jnp.asarray(slot_tables, jnp.int32),
                 }
-                logits, self.cache = self._decode_jit(self.params,
-                                                      self.cache, batch)
+                logits, self.cache, *hit = self._decode_jit(
+                    self.params, self.cache, batch)
             self.last_logits = logits
+            if hit:
+                with span("decode.sample"):
+                    out, hit = self._jax.device_get(
+                        (jnp.argmax(logits[:, : self.vocab], axis=-1),
+                         hit[0]))
+                with span("decode.experts", experts_hit=int(hit)):
+                    return np.asarray(out).astype(np.int64)
             with span("decode.sample"):
                 return np.asarray(
                     jnp.argmax(logits[:, : self.vocab], axis=-1)

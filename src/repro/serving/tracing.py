@@ -17,10 +17,22 @@ beside the device's events (XProf's trace viewer, or
   each, fewer under a lookback window) and ``width`` (the table's columns
   per row); inside it ``serve.decode.launch`` (the
   batch's upload and the program's dispatch) and ``serve.decode.sample``
-  (the argmax over the logits and the tokens' read-back).
+  (the argmax over the logits and the tokens' read-back);
+- under a grouped MoE (``router="sigmoid_bias"``) only:
+  ``serve.decode.experts``, inside ``serve.decode`` after the read-back:
+  ``experts_hit``, the (layer, held expert) pairs that computed at least
+  one row of the step, which the decode program returns beside the
+  logits and the read-back brings with the tokens; and
+  ``serve.prefill.experts``, inside ``serve.prefill``: ``held_rows``, the
+  prompt's (layer, token, choice) rows that the held experts computed.
 
 The decode program is ``jit_serve_decode`` in the device trace and each
-prefill program ``jit_serve_prefill``.
+prefill program ``jit_serve_prefill``. Inside them ``jax.named_scope``
+marks ``mla_decode`` (absorbed latent attention), ``moe_route`` (the
+router's scores and top-k), ``moe_experts`` (the held experts' sort,
+grouped matmuls and combine) and ``moe_shared`` (the shared experts) in
+the ops' metadata; the latent kernel's instruction is named
+``mla_decode_attention``, and the grouped matmuls' ``ragged-dot``.
 """
 from __future__ import annotations
 

@@ -133,6 +133,59 @@ def test_gptj_decode_step_paged_fits_one_chip(one_chip):
     assert held < 16e9, held  # one v5e holds 16 GB
 
 
+def test_flash_attention_pallas_mla_prefill(one_chip):
+    # Moonlight's expanded MLA prefill: q and k 192 wide, v 128
+    qk = ((1, 16, 512, 192), BF16)
+    text = _compile(functools.partial(ops.flash_attention, impl="pallas"),
+                    one_chip, qk, qk, ((1, 16, 512, 128), BF16))
+    assert "tpu_custom_call" in text
+
+
+def test_latent_decode_attention_pallas_cell_geometry(one_chip):
+    # the moonlight-chat cell: 32 slots of 128 pages of 16, a pool of 4096
+    # pages of latent rows (576 values padded to 640); the kernel is named
+    # in the program
+    def f(q, pool, pos, tbl):
+        return ops.latent_decode_attention(q, pool, pos, tbl, v_width=512,
+                                           scale=192 ** -0.5, impl="pallas")
+
+    text = _compile(f, one_chip, ((32, 16, 640), BF16),
+                    ((4096, 1, 16, 640), BF16), ((32,), I32),
+                    ((32, 128), I32))
+    assert "%mla_decode_attention" in text
+
+
+def test_moonlight_decode_step_paged_fits_one_chip(one_chip):
+    # rank 0 of EP4: 16 of 64 experts held, every width as published
+    from repro.configs.base import get_config
+    from repro.kernels import registry as kernels
+    from repro.models import registry, transformer
+    from repro.serving import paged_cache
+
+    cfg = get_config("moonlight-16b-a3b").replace(experts_held=16)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(registry.param_shapes(cfg))
+    cache = on_chip(jax.eval_shape(lambda: paged_cache.init_paged_cache(
+        cfg, num_blocks=4096, block_size=16)))
+    batch = on_chip({"token": jax.ShapeDtypeStruct((32,), I32),
+                     "position": jax.ShapeDtypeStruct((32,), I32),
+                     "block_table": jax.ShapeDtypeStruct((32, 128), I32)})
+    attn = functools.partial(ops.latent_decode_attention, impl="pallas")
+    with kernels.default_impl("pallas"):
+        step = jax.jit(lambda p, c, b: transformer.decode_step_paged(
+            p, cfg, c, b, attn_fn=attn, stats=True), donate_argnums=(1,))
+        compiled = step.lower(params, cache, batch).compile()
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert held < 16e9, held
+    text = compiled.as_text()
+    assert "%mla_decode_attention" in text and "ragged-dot" in text
+
+
 # Refused by the TPU compiler today; each needs its own kernel rework. A
 # test counts as the expected failure only when the compiler's own message
 # matches; any other error fails it.

@@ -246,7 +246,8 @@ def _fa_ref(q, k, v, *, causal, window, q_offset, scale, precision=None,
 def decode_attention(q, k, v, position, *, window=0, scale=None,
                      precision=None, impl=None, mesh=None, bs=None,
                      paged=False, block_table=None, k_scale=None,
-                     v_scale=None, pos_offset=0, return_lse=False):
+                     v_scale=None, pos_offset=0, return_lse=False,
+                     layer=None):
     """Single-token attention against a cache. Linear in cache length.
 
     ``precision`` holds the KV cache quantized — narrow values plus one
@@ -257,10 +258,13 @@ def decode_attention(q, k, v, position, *, window=0, scale=None,
     ``paged=True`` switches k/v to the serving engine's block-pool layout:
     ``(P, K, bs, D)`` physical pages plus a ``(B, NB)`` int32
     ``block_table`` mapping each sequence's logical cache block to its
-    pool slot (``serving.paged_cache``). The gathered pages stream through
-    the same online-softmax body as the contiguous cache, so the two
-    layouts are bitwise-equal at matching geometry; ``k_scale``/``v_scale``
-    pass a pre-quantized pool's per-row fp32 scales. ``pos_offset`` is the
+    pool slot (``serving.paged_cache``). With ``layer`` (an int or a traced
+    int32 scalar) k/v are the engine's stacked pools, ``(nl, P, K, bs, D)``,
+    and attention reads that layer's pages in place: no layer's pool is
+    sliced out. The gathered pages stream through the same online-softmax
+    body as the contiguous cache, so the two layouts are bitwise-equal at
+    matching geometry; ``k_scale``/``v_scale`` pass a pre-quantized pool's
+    per-row fp32 scales, stacked like the pools. ``pos_offset`` is the
     absolute position of logical block 0 (nonzero for ring-decode cache
     shards) and ``return_lse=True`` adds the (B, H) fp32 log-sum-exp the
     per-shard ``online_softmax_merge`` fold consumes. All paged kwargs
@@ -271,13 +275,17 @@ def decode_attention(q, k, v, position, *, window=0, scale=None,
         raise TypeError("decode_attention: paged=True requires block_table")
     if block_table is not None and not paged:
         raise TypeError("decode_attention: block_table requires paged=True")
+    if layer is not None and not paged:
+        raise TypeError("decode_attention: layer requires paged=True")
     if paged:
-        if k.ndim != 4 or k.shape[:3] != v.shape[:3]:
+        layer, (k, v, k_scale, v_scale) = stacked_pools(
+            layer, k, v, k_scale, v_scale)
+        if k.ndim != 5 or k.shape[:4] != v.shape[:4]:
             raise ValueError(
-                f"decode_attention(paged): pools must be (P, K, bs, D), got "
-                f"k={k.shape} v={v.shape}"
+                f"decode_attention(paged): pools must be (P, K, bs, D), or "
+                f"(nl, P, K, bs, D) with layer=, got k={k.shape} v={v.shape}"
             )
-        paged_kwargs = {"block_table": block_table}
+        paged_kwargs = {"block_table": block_table, "layer": layer}
         if k_scale is not None:
             paged_kwargs.update(k_scale=k_scale, v_scale=v_scale)
         blocks = {}  # the pool's page extent pins bs; no registry tile
@@ -304,21 +312,32 @@ def decode_attention(q, k, v, position, *, window=0, scale=None,
     )
 
 
+def stacked_pools(layer, *pools):
+    """``(layer, pools)`` for the paged kernels, which read a stack of
+    layers: one layer's pools (``layer`` None) are layer 0 of a stack of
+    one. None pools (absent scales) stay None."""
+    if layer is not None:
+        return layer, pools
+    return 0, tuple(None if x is None else x[None] for x in pools)
+
+
 @registry.register_kernel("decode_attention", impl="xla")
 def _decode_xla(q, k, v, position, *, window, scale, precision=None, bs=None,
                 block_table=None, k_scale=None, v_scale=None, pos_offset=0,
-                return_lse=False):
+                return_lse=False, layer=None):
     return _xla.decode_attention_xla(
         q, k, v, position, window=window, scale=scale, bs=bs,
         precision=precision, block_table=block_table, k_scale=k_scale,
         v_scale=v_scale, pos_offset=pos_offset, return_lse=return_lse,
+        layer=layer,
     )
 
 
 @registry.register_stream_kernel("decode_attention")
 def _decode_stream(q, k, v, position, *, window, scale, precision=None,
                    bs=None, block_table=None, k_scale=None, v_scale=None,
-                   pos_offset=0, return_lse=False, interpret=False):
+                   pos_offset=0, return_lse=False, layer=None,
+                   interpret=False):
     """Paged calls stream the live pages through the block table
     (``kernels/paged_attention.py``). A contiguous cache has no table to
     stream through: those calls run the blocked xla form unchanged."""
@@ -331,8 +350,8 @@ def _decode_stream(q, k, v, position, *, window, scale, precision=None,
     from repro.kernels import paged_attention as _pa
 
     return _pa.paged_decode_attention_pallas(
-        q, k, v, position, block_table, window=window, scale=scale,
-        precision=precision, k_scale=k_scale, v_scale=v_scale,
+        q, k, v, position, block_table, layer=layer, window=window,
+        scale=scale, precision=precision, k_scale=k_scale, v_scale=v_scale,
         pos_offset=pos_offset, return_lse=return_lse, interpret=interpret,
     )
 
@@ -340,12 +359,12 @@ def _decode_stream(q, k, v, position, *, window, scale, precision=None,
 @registry.register_kernel("decode_attention", impl="ref")
 def _decode_ref(q, k, v, position, *, window, scale, precision=None, bs=None,
                 block_table=None, k_scale=None, v_scale=None, pos_offset=0,
-                return_lse=False):
+                return_lse=False, layer=None):
     if block_table is not None:
         return _ref.decode_attention_paged_ref(
-            q, k, v, block_table, position, window=window, scale=scale,
-            precision=precision, k_scale=k_scale, v_scale=v_scale,
-            pos_offset=pos_offset, return_lse=return_lse,
+            q, k, v, block_table, position, layer=layer, window=window,
+            scale=scale, precision=precision, k_scale=k_scale,
+            v_scale=v_scale, pos_offset=pos_offset, return_lse=return_lse,
         )
     if precision is not None:
         return _ref.decode_attention_scaled_ref(
@@ -358,50 +377,56 @@ def _decode_ref(q, k, v, position, *, window, scale, precision=None, bs=None,
 
 
 def latent_decode_attention(q, pool, position, block_table, *, v_width,
-                            scale, impl=None):
+                            scale, impl=None, layer=None):
     """Absorbed latent attention (MLA) of one new token per sequence
     against the serving engine's latent page pool.
 
     ``q``: (B, H, D), each head's query in latent space followed by its
     rotary part; ``pool``: (P, 1, bs, D) pages of latent rows (the latent,
-    then the shared rotary key); ``block_table``: (B, NB) int32 pool slots.
+    then the shared rotary key), or with ``layer`` the engine's stacked
+    (nl, P, 1, bs, D) pool, read at that layer in place; ``block_table``:
+    (B, NB) int32 pool slots.
     Every head attends over the rows' whole width, and the values are their
     first ``v_width`` columns, so the pool is read once for both. Returns
     (B, H, v_width). Its Pallas form is an instance of ``decode_attention``'s
     paged kernel and takes that op's ``pages`` geometry; it has no block
     table or partition rule of its own (one device only: the engine refuses
     a mesh for a latent pool)."""
-    if pool.ndim != 4 or pool.shape[1] != 1 or pool.shape[3] != q.shape[-1]:
+    layer, (pool,) = stacked_pools(layer, pool)
+    if pool.ndim != 5 or pool.shape[2] != 1 or pool.shape[4] != q.shape[-1]:
         raise ValueError(
             f"latent_decode_attention: pool must be (P, 1, bs, {q.shape[-1]})"
-            f", got {pool.shape}")
+            f", or (nl, P, 1, bs, {q.shape[-1]}) with layer=, got "
+            f"{pool.shape}")
     return kernel_call("latent_decode_attention", q, pool, position,
-                       block_table, v_width=v_width, scale=scale, impl=impl)
+                       block_table, layer=layer, v_width=v_width, scale=scale,
+                       impl=impl)
 
 
 @registry.register_stream_kernel("latent_decode_attention")
-def _latent_stream(q, pool, position, block_table, *, v_width, scale,
+def _latent_stream(q, pool, position, block_table, *, layer, v_width, scale,
                    interpret=False):
     from repro.kernels import paged_attention as _pa
 
     return _pa.latent_decode_attention_pallas(
-        q, pool, position, block_table, v_width=v_width, scale=scale,
-        interpret=interpret,
+        q, pool, position, block_table, layer=layer, v_width=v_width,
+        scale=scale, interpret=interpret,
     )
 
 
 @registry.register_kernel("latent_decode_attention", impl="xla")
-def _latent_xla(q, pool, position, block_table, *, v_width, scale):
+def _latent_xla(q, pool, position, block_table, *, layer, v_width, scale):
     return _xla.decode_attention_xla(
         q, pool, pool[..., :v_width], position, scale=scale,
-        block_table=block_table,
+        block_table=block_table, layer=layer,
     )
 
 
 @registry.register_kernel("latent_decode_attention", impl="ref")
-def _latent_ref(q, pool, position, block_table, *, v_width, scale):
+def _latent_ref(q, pool, position, block_table, *, layer, v_width, scale):
     return _ref.decode_attention_paged_ref(
-        q, pool, pool[..., :v_width], block_table, position, scale=scale,
+        q, pool, pool[..., :v_width], block_table, position, layer=layer,
+        scale=scale,
     )
 
 

@@ -1,12 +1,15 @@
 """Paged decode attention: one new token per sequence against the serving
 engine's page pool, read through the block table.
 
-The pool is page-major, ``(P, K, bs, D)``: one page of all KV heads is one
+The pool is the serving engine's stacked one, layer- then page-major,
+``(nl, P, K, bs, D)``: one page of all KV heads of one layer is one
 contiguous ``(K, bs, D)`` tile. The block table ``(B, NB)`` maps each
-sequence's logical page to its pool slot. This is the paper's indirect
-stream (C2, Fig. 4b): the table is scalar-prefetched and the K/V streams'
-index maps read it, so the pipeliner's DMAs fetch the live pages straight
-from the pool and no gathered copy of the table's pages is ever made.
+sequence's logical page to its pool slot, and ``layer`` picks the layer.
+This is the paper's indirect stream (C2, Fig. 4b): the table and the layer
+are scalar-prefetched and the K/V streams' index maps read them, so the
+pipeliner's DMAs fetch the live pages straight from the stacked pool, and
+neither a gathered copy of the table's pages nor a slice of the layer's
+pool is ever made.
 
 Grid ``(B, NB / pages)``: one step covers ``pages`` consecutive table
 columns of one row, each bound to its own K and V stream (``pages`` comes
@@ -63,8 +66,9 @@ def _live_bounds(position, *, bs: int, nb: int, window: int, pos_offset):
     return first, last, lo, jnp.maximum(last // bs, lo)
 
 
-def _paged_kernel(tbl_ref, first_ref, last_ref, lo_ref, hi_ref, q_ref, *refs,
-                  pages, bs, nsteps, scale, scaled, return_lse, shared_v=0):
+def _paged_kernel(tbl_ref, first_ref, last_ref, lo_ref, hi_ref, layer_ref,
+                  q_ref, *refs, pages, bs, nsteps, scale, scaled, return_lse,
+                  shared_v=0):
     # refs: `pages` K page refs, `pages` V page refs (none where the values
     # are K's first `shared_v` columns), then (scaled) as many K and V
     # scale refs; then o (and lse); then the (m, l, acc) scratch
@@ -95,9 +99,10 @@ def _paged_kernel(tbl_ref, first_ref, last_ref, lo_ref, hi_ref, q_ref, *refs,
         # D), so that each head's dot spans them all; a narrow pool is
         # dequantized at use
         if scale_refs is None:
-            return jnp.concatenate([r[0] for r in refs], axis=1)
+            return jnp.concatenate([r[0, 0] for r in refs], axis=1)
         return jnp.concatenate(
-            [r[0].astype(jnp.float32) * s[0] for r, s in zip(refs, scale_refs)],
+            [r[0, 0].astype(jnp.float32) * s[0, 0]
+             for r, s in zip(refs, scale_refs)],
             axis=1)
 
     @pl.when(jnp.logical_and(c0 <= hi_ref[b], c0 + pages > lo_ref[b]))
@@ -139,9 +144,10 @@ def paged_decode_program(B, K, G, D, bs, nbp, pages, dtype, pool_dtype,
     """The paged decode as a stream program over grid ``(B, nbp / pages)``.
 
     ``index_args`` are the scalar-prefetched ``(table, first, last, lo,
-    hi)``: the row-major ``(B * nbp,)`` table and each row's bounds
-    (``_live_bounds``). Page stream ``i`` of step ``j`` reads table column
-    ``j * pages + i``, clamped into the row's live columns ``[lo, hi]``.
+    hi, layer)``: the row-major ``(B * nbp,)`` table, each row's bounds
+    (``_live_bounds``) and the ``(1,)`` layer index into the stacked pool.
+    Page stream ``i`` of step ``j`` reads table column ``j * pages + i``,
+    clamped into the row's live columns ``[lo, hi]``, of the layer.
     ``scaled`` binds a per-row fp32 scale stream beside every K and V page
     stream. ``shared_v`` > 0 binds no V streams: the values are the first
     ``shared_v`` columns of the K pages (latent attention), and the output
@@ -150,9 +156,9 @@ def paged_decode_program(B, K, G, D, bs, nbp, pages, dtype, pool_dtype,
     Dv = shared_v or D
 
     def page_stream(i, block, dt):
-        def index_map(b, j, tbl, first, last, lo, hi):
+        def index_map(b, j, tbl, first, last, lo, hi, layer):
             col = jnp.minimum(jnp.maximum(j * pages + i, lo[b]), hi[b])
-            return (tbl[b * nbp + col], 0, 0, 0)
+            return (layer[0], tbl[b * nbp + col], 0, 0, 0)
 
         return IndirectStream(block, index_map, dtype=dt)
 
@@ -161,12 +167,12 @@ def paged_decode_program(B, K, G, D, bs, nbp, pages, dtype, pool_dtype,
 
     in_streams = [AffineStream((1, K, G, D), lambda b, j: (b, 0, 0, 0),
                                dtype=dtype)]
-    in_streams += streams((1, K, bs, D), pool_dtype)  # K pages
+    in_streams += streams((1, 1, K, bs, D), pool_dtype)  # K pages
     if not shared_v:
-        in_streams += streams((1, K, bs, D), pool_dtype)  # V pages
+        in_streams += streams((1, 1, K, bs, D), pool_dtype)  # V pages
     if scaled:
-        in_streams += streams((1, K, bs, 1), jnp.float32)
-        in_streams += streams((1, K, bs, 1), jnp.float32)
+        in_streams += streams((1, 1, K, bs, 1), jnp.float32)
+        in_streams += streams((1, 1, K, bs, 1), jnp.float32)
     out_streams = [AffineStream((1, K, G, Dv), lambda b, j: (b, 0, 0, 0),
                                 dtype=dtype)]
     out_shapes = [jax.ShapeDtypeStruct((B, K, G, Dv), dtype)]
@@ -197,23 +203,29 @@ def paged_decode_program(B, K, G, D, bs, nbp, pages, dtype, pool_dtype,
     )
 
 
+def _layer_arg(layer):
+    return jnp.asarray(layer, jnp.int32).reshape(1)
+
+
 def paged_decode_attention_pallas(
     q: jax.Array,  # (B, H, D)
-    k: jax.Array,  # (P, K, bs, D) page pool
+    k: jax.Array,  # (nl, P, K, bs, D) stacked page pool
     v: jax.Array,
     position: jax.Array,  # (B,)
     block_table: jax.Array,  # (B, NB) int32 pool slots
     *,
+    layer,  # int or traced int32 scalar: the pool's layer to read
     window: int = 0,
     scale: float | None = None,
     precision=None,
-    k_scale=None,  # (P, K, bs, 1) fp32 pool scales (a pool held narrow)
+    k_scale=None,  # (nl, P, K, bs, 1) fp32 pool scales (a pool held narrow)
     v_scale=None,
     pos_offset=0,
     return_lse: bool = False,
     interpret: bool = False,
 ):
-    """``decode_attention_xla``'s paged semantics, reading only live pages.
+    """``decode_attention_xla``'s paged semantics on layer ``layer`` of a
+    stacked pool, reading only live pages.
 
     A pool held narrow dequantizes each page at use (``k_scale``/
     ``v_scale``); ``precision`` without scales quantizes the pool first,
@@ -221,7 +233,7 @@ def paged_decode_attention_pallas(
     traced) shifts the table's positions; ``return_lse`` adds the (B, H)
     fp32 log-sum-exp."""
     B, H, D = q.shape
-    K, bs = k.shape[1], k.shape[2]
+    K, bs = k.shape[2], k.shape[3]
     G = H // K
     nb = block_table.shape[1]
     scale = scale if scale is not None else 1.0 / np.sqrt(D)
@@ -237,8 +249,8 @@ def paged_decode_attention_pallas(
     scaled = k_scale is not None
     program = paged_decode_program(
         B, K, G, D, bs, nbp, pages, q.dtype, k.dtype,
-        (table.reshape(-1), *bounds), scale=scale, scaled=scaled,
-        return_lse=return_lse,
+        (table.reshape(-1), *bounds, _layer_arg(layer)), scale=scale,
+        scaled=scaled, return_lse=return_lse,
     )
     operands = [q.reshape(B, K, G, D)] + [k] * pages + [v] * pages
     if scaled:
@@ -252,26 +264,29 @@ def paged_decode_attention_pallas(
 
 def latent_decode_attention_pallas(
     q: jax.Array,  # (B, H, D): the absorbed query, latent then rotary part
-    pool: jax.Array,  # (P, 1, bs, D) page pool of latent rows
+    pool: jax.Array,  # (nl, P, 1, bs, D) stacked page pool of latent rows
     position: jax.Array,  # (B,)
     block_table: jax.Array,  # (B, NB) int32 pool slots
     *,
+    layer,  # int or traced int32 scalar: the pool's layer to read
     v_width: int,
     scale: float,
     interpret: bool = False,
 ):
-    """Absorbed latent attention of one new token per sequence, reading
-    only live pages: scores over each row's whole width, values its first
-    ``v_width`` columns. Returns (B, H, v_width)."""
+    """Absorbed latent attention of one new token per sequence against
+    layer ``layer`` of a stacked latent pool, reading only live pages:
+    scores over each row's whole width, values its first ``v_width``
+    columns. Returns (B, H, v_width)."""
     B, H, D = q.shape
-    bs, nb = pool.shape[2], block_table.shape[1]
+    bs, nb = pool.shape[3], block_table.shape[1]
     pages = min(resolve_blocks("decode_attention")["pages"], nb)
     nbp = nb + (-nb) % pages
     table = jnp.pad(block_table.astype(jnp.int32), ((0, 0), (0, nbp - nb)))
     bounds = _live_bounds(position, bs=bs, nb=nb, window=0, pos_offset=0)
     program = paged_decode_program(
         B, 1, H, D, bs, nbp, pages, q.dtype, pool.dtype,
-        (table.reshape(-1), *bounds), scale=scale, shared_v=v_width,
+        (table.reshape(-1), *bounds, _layer_arg(layer)), scale=scale,
+        shared_v=v_width,
     )
     out = stream_compute(program, q.reshape(B, 1, H, D), *[pool] * pages,
                          interpret=interpret)

@@ -157,15 +157,16 @@ def decode_attention_scaled_ref(q, k, v, position, *, precision, **kwargs):
 
 def decode_attention_paged_ref(
     q,  # (B, H, D)
-    k,  # (P, K, bs, D) physical block pool
-    v,  # (P, K, bs, D)
+    k,  # (nl, P, K, bs, D) stacked physical block pools
+    v,  # (nl, P, K, bs, D)
     block_table,  # (B, NB) int32 pool slots per logical cache block
     position,  # (B,)
     *,
+    layer,  # the stacked pools' layer to read
     window: int = 0,
     scale: float | None = None,
     precision=None,
-    k_scale=None,  # (P, K, bs, 1) fp32 pool scales (pre-quantized cache)
+    k_scale=None,  # (nl, P, K, bs, 1) fp32 pool scales (pre-quantized cache)
     v_scale=None,
     pos_offset=0,
     return_lse: bool = False,
@@ -175,6 +176,9 @@ def decode_attention_paged_ref(
     — the ground truth the blocked gather path must match bitwise."""
     from repro.core import precision as prec
 
+    k, v = k[layer], v[layer]
+    if k_scale is not None:
+        k_scale, v_scale = k_scale[layer], v_scale[layer]
     if precision is not None and k_scale is None:
         k, k_scale, v, v_scale = prec.quantize_kv_cache(k, v, precision)
     if k_scale is not None:

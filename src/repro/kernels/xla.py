@@ -230,7 +230,8 @@ def flash_attention_scaled_xla(q, k, v, precision, *, causal=True, window=0,
 
 def decode_attention_xla(q, k, v, position, *, window=0, scale=None, bs=None,
                          precision=None, block_table=None, k_scale=None,
-                         v_scale=None, pos_offset=0, return_lse=False):
+                         v_scale=None, pos_offset=0, return_lse=False,
+                         layer=None):
     """Blocked single-token attention against a cache (online softmax over
     cache blocks, the memory-bound decode form GPT-J hits every step).
 
@@ -246,15 +247,16 @@ def decode_attention_xla(q, k, v, position, *, window=0, scale=None, bs=None,
     stream traffic shrink by the compute dtype's width ratio.
 
     ``block_table`` switches the cache operands to the *paged* layout: k/v
-    are physical block pools ``(P, K, bs, D)`` and ``block_table`` is a
-    ``(B, NB)`` int32 map from each sequence's logical cache block to its
-    pool slot. The pool's own block extent pins ``bs`` (the page size is
-    the stream tile), the gathered blocks stream through the *same* online
-    softmax body as the contiguous path — so the two layouts are bitwise
-    equal whenever the contiguous length is ``NB * bs``. Table entries past
-    a sequence's ``position`` may point anywhere valid (the mask makes
-    those blocks exact no-ops). ``k_scale``/``v_scale`` pass pre-quantized
-    pool scales (``(P, K, bs, 1)``) so a cache held narrow by the serving
+    are the serving engine's stacked physical block pools ``(nl, P, K, bs,
+    D)``, read at layer ``layer``, and ``block_table`` is a ``(B, NB)``
+    int32 map from each sequence's logical cache block to its pool slot.
+    The pool's own block extent pins ``bs`` (the page size is the stream
+    tile), the gathered blocks stream through the *same* online softmax
+    body as the contiguous path — so the two layouts are bitwise equal
+    whenever the contiguous length is ``NB * bs``. Table entries past a
+    sequence's ``position`` may point anywhere valid (the mask makes those
+    blocks exact no-ops). ``k_scale``/``v_scale`` pass pre-quantized pool
+    scales (``(nl, P, K, bs, 1)``) so a cache held narrow by the serving
     engine skips the quantize-at-use step. ``pos_offset`` shifts the
     absolute position of logical block 0 (the cache-shard offset ring
     decode folds over); ``return_lse`` additionally returns the (B, H)
@@ -262,7 +264,7 @@ def decode_attention_xla(q, k, v, position, *, window=0, scale=None, bs=None,
     """
     B, H, D = q.shape
     Dv = v.shape[-1]
-    K = k.shape[1]
+    K = k.shape[-3]
     G = H // K
     scale = scale if scale is not None else 1.0 / np.sqrt(D)
     paged = block_table is not None
@@ -271,12 +273,12 @@ def decode_attention_xla(q, k, v, position, *, window=0, scale=None, bs=None,
 
         k, k_scale, v, v_scale = prec.quantize_kv_cache(k, v, precision)
     if paged:
-        bs = k.shape[2]  # the pool's page size IS the stream tile
+        bs = k.shape[-2]  # the pool's page size IS the stream tile
         nb = block_table.shape[1]
         S = nb * bs
         # gather pool pages into the (nb, B, K, bs, d) stream the scan eats
         def blk(x):
-            return jnp.moveaxis(x[block_table], 1, 0)
+            return jnp.moveaxis(x[layer, block_table], 1, 0)
 
         kb, vb = blk(k), blk(v)
         ksb = blk(k_scale) if k_scale is not None else jnp.zeros((nb,))

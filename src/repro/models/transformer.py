@@ -277,22 +277,25 @@ def mla_attention(p, cfg, x, cos, sin, *, q_offset=0):
     return out, jnp.concatenate([c, k_pe[:, :, 0]], axis=-1)
 
 
-def mla_decode_paged(p, cfg, x, cos, sin, pool, block_table, position, *,
-                     attn_fn=None):
-    """One layer's absorbed MLA decode against a latent page pool.
+def mla_decode_paged(p, cfg, x, cos, sin, pool, block_table, position, layer,
+                     *, attn_fn=None):
+    """Layer ``layer``'s absorbed MLA decode against the stacked latent
+    page pool.
 
-    ``pool``: (P, 1, bs, width) pages of ``[c, k_pe]`` rows, zero-padded to
-    the pool's ``width`` (``serving.paged_cache.LANES``). The new token's
-    row is written at page ``block_table[b, pos // bs]``, row ``pos % bs``;
-    each head's query moves into latent space (``q_nope W_uk``) beside its
-    rotary part, padded alike, attends over the latent rows
-    (``attn_fn``, by default the registered ``latent_decode_attention``),
-    and the latent output goes back through ``W_uv`` and ``wo``."""
+    ``pool``: (nl, P, 1, bs, width) pages of ``[c, k_pe]`` rows,
+    zero-padded to the pool's ``width`` (``serving.paged_cache.LANES``).
+    The new token's row is written in place at ``[layer, block_table[b,
+    pos // bs], 0, pos % bs]``; each head's query moves into latent space
+    (``q_nope W_uk``) beside its rotary part, padded alike, attends over
+    the layer's latent rows (``attn_fn``, by default the registered
+    ``latent_decode_attention``, which reads the stacked pool at
+    ``layer``), and the latent output goes back through ``W_uv`` and
+    ``wo``. Returns (out, the updated stacked pool)."""
     with jax.named_scope("mla_decode"):
         B, _ = x.shape
         H, r = cfg.num_heads, cfg.kv_lora_rank
         dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
-        bs = pool.shape[2]
+        bs = pool.shape[3]
         q, c, k_pe = _mla_project(p, cfg, x)
         q_pe = L.apply_rope(q[:, None, :, dn:], cos[:, None],
                             sin[:, None])[:, 0]
@@ -303,14 +306,14 @@ def mla_decode_paged(p, cfg, x, cos, sin, pool, block_table, position, *,
         pad = ((0, 0), (0, pool.shape[-1] - cfg.latent_dim))
         row = jnp.pad(jnp.concatenate([c, k_pe], axis=-1), pad)
         row = row.astype(pool.dtype)
-        pool = pool.at[phys, 0, position % bs].set(row)
+        pool = pool.at[layer, phys, 0, position % bs].set(row)
         wkv_b = p["wkv_b"].reshape(r, H, dn + dv)
         q_lat = jnp.einsum("bhn,rhn->bhr", q[..., :dn], wkv_b[..., :dn],
                            preferred_element_type=jnp.float32)
         q = jnp.pad(jnp.concatenate([q_lat.astype(x.dtype), q_pe], axis=-1),
                     ((0, 0),) + pad)
         attn = attn_fn or ops.latent_decode_attention
-        o = attn(q, pool, position, block_table, v_width=r,
+        o = attn(q, pool, position, block_table, layer=layer, v_width=r,
                  scale=1.0 / math.sqrt(cfg.qk_head_dim))
         o = jnp.einsum("bhr,rhv->bhv", o, wkv_b[..., dn:],
                        preferred_element_type=jnp.float32).astype(x.dtype)
@@ -602,25 +605,28 @@ def _ffn_decode(p, cfg, x):
 
 
 def attention_decode_paged(p, cfg, x, cos, sin, k_pool, v_pool, k_scale,
-                           v_scale, block_table, position, *, window=0,
-                           policy=None, attn_fn=None):
-    """One layer's decode against a paged KV pool.
+                           v_scale, block_table, position, layer, *,
+                           window=0, policy=None, attn_fn=None):
+    """Layer ``layer``'s decode against the stacked paged KV pools.
 
-    ``k_pool``/``v_pool``: (P, K, bs, hd) physical pages (+ per-row fp32
-    scales when ``policy`` holds the cache narrow); ``block_table``:
-    (B, NB) int32 pool slots per sequence; ``position``: (B,). The new
-    token's K/V is written into page ``block_table[b, pos // bs]`` at row
-    ``pos % bs`` (quantized per row under ``policy`` — the same
-    quantization ``precision.quantize_kv_cache`` applies), then attention
-    runs through the registered paged ``decode_attention`` — or through
+    ``k_pool``/``v_pool``: (nl, P, K, bs, hd) physical pages of every layer
+    (+ per-row fp32 scales, stacked alike, when ``policy`` holds the cache
+    narrow); ``block_table``: (B, NB) int32 pool slots per sequence;
+    ``position``: (B,). The new token's K/V is written in place, B rows at
+    ``[layer, block_table[b, pos // bs], :, pos % bs]`` (quantized per row
+    under ``policy`` — the same quantization ``precision.quantize_kv_cache``
+    applies), then attention reads the layer's pages of the stacked pools
+    through the registered paged ``decode_attention`` — or through
     ``attn_fn(q, k_pool, v_pool, k_scale, v_scale, block_table, position,
-    window)`` when the serving layer injects a distribution (ring decode).
-    Every row writes every step: inactive slots point at the shared
-    scratch page, which live prefixes never reference."""
+    window, layer)`` when the serving layer injects a distribution (ring
+    decode). No layer's pool is sliced out or stacked again. Every row
+    writes every step: inactive slots point at the shared scratch page,
+    which live prefixes never reference. Returns (out, the updated pools
+    and scales)."""
     B, d = x.shape
     hd = cfg.resolved_head_dim()
     H, K = cfg.num_heads, cfg.num_kv_heads
-    bs = k_pool.shape[2]
+    bs = k_pool.shape[3]
 
     q = (x @ p["wq"]).astype(x.dtype)
     k = (x @ p["wk"]).astype(x.dtype)
@@ -642,7 +648,7 @@ def attention_decode_paged(p, cfg, x, cos, sin, k_pool, v_pool, k_scale,
     offset = position % bs
     heads = jnp.arange(K)[None, :]
     def at(pool):
-        return pool.at[phys[:, None], heads, offset[:, None]]
+        return pool.at[layer, phys[:, None], heads, offset[:, None]]
 
     if policy is not None:
         from repro.core import precision as prec
@@ -659,11 +665,11 @@ def attention_decode_paged(p, cfg, x, cos, sin, k_pool, v_pool, k_scale,
     if attn_fn is None:
         o = ops.decode_attention(
             q, k_pool, v_pool, position, paged=True, block_table=block_table,
-            k_scale=k_scale, v_scale=v_scale, window=window,
+            k_scale=k_scale, v_scale=v_scale, window=window, layer=layer,
         )
     else:
         o = attn_fn(q, k_pool, v_pool, k_scale, v_scale, block_table,
-                    position, window)
+                    position, window, layer)
     o = o.reshape(B, H * hd)
     o = jnp.einsum(
         "bh,hd->bd", o, p["wo"], preferred_element_type=jnp.float32
@@ -695,12 +701,16 @@ def decode_step_paged(params, cfg, cache, batch, *, attn_fn=None,
         else (None, None)
     )
 
-    def body(h, xs):
-        lp, kp, vp, ks, vs = xs
+    # the pools ride in the carry, each layer writing its rows in place,
+    # so that no layer's pool is sliced out of them or stacked back
+    def body(carry, xs):
+        h, kp, vp, ks, vs = carry
+        lp, layer = xs
         n = L.rms_norm(h, lp["attn_norm"], cfg.norm_eps)
         a, kp, vp, ks, vs = attention_decode_paged(
             lp, cfg, n, cos, sin, kp, vp, ks, vs, block_table, position,
-            window=cfg.sliding_window, policy=cache.policy, attn_fn=attn_fn,
+            layer, window=cfg.sliding_window, policy=cache.policy,
+            attn_fn=attn_fn,
         )
         if cfg.parallel_block:
             m, _ = _ffn_decode(lp, cfg, n)
@@ -710,12 +720,12 @@ def decode_step_paged(params, cfg, cache, batch, *, attn_fn=None,
             n = L.rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
             m, _ = _ffn_decode(lp, cfg, n)
             h = h + m
-        return h, (kp, vp, ks, vs)
+        return (h, kp, vp, ks, vs), None
 
-    h, (kp, vp, ks, vs) = jax.lax.scan(
-        body, h,
-        (params["layers"], cache.k_pool, cache.v_pool,
-         cache.k_scale, cache.v_scale),
+    (h, kp, vp, ks, vs), _ = jax.lax.scan(
+        body,
+        (h, cache.k_pool, cache.v_pool, cache.k_scale, cache.v_scale),
+        (params["layers"], jnp.arange(cache.k_pool.shape[0])),
         unroll=cfg.scan_unroll,
     )
     h = L.rms_norm(h, params["final_norm"], cfg.norm_eps)
@@ -733,7 +743,8 @@ def latent_decode_step(params, cfg, cache, batch, *, attn_fn=None,
                        stats=False):
     """``decode_step_paged`` under MLA: ``cache.k_pool`` holds the latent
     rows of the scanned layers and ``cache.lead_pool`` those of the leading
-    dense layers, each (n, P, 1, bs, width), and there is no V pool.
+    dense layers, each (n, P, 1, bs, width), and there is no V pool. Each
+    scan carries its stacked pool and updates it in place.
     A row whose first table column is the scratch page (``NULL_BLOCK``,
     page 0) is an empty slot and reaches no routed expert. With ``stats``
     a third value ``{"experts_hit"}`` counts the (layer, held expert) pairs
@@ -746,22 +757,27 @@ def latent_decode_step(params, cfg, cache, batch, *, attn_fn=None,
     h = jnp.take(params["embed"], tokens, axis=0)
     cos, sin = L.rope_cos_sin(position, _rope_dim(cfg), cfg.rope_theta)
 
-    def body(h, xs):
-        lp, pool = xs
+    def body(carry, xs):
+        h, pool = carry
+        lp, layer = xs
         n = L.rms_norm(h, lp["attn_norm"], cfg.norm_eps)
         a, pool = mla_decode_paged(lp, cfg, n, cos, sin, pool, block_table,
-                                   position, attn_fn=attn_fn)
+                                   position, layer, attn_fn=attn_fn)
         h = h + a
         n = L.rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
         m, _, sizes = _ffn(lp, cfg, n[:, None], live[:, None])
         hit = None if sizes is None else jnp.count_nonzero(sizes)
-        return h + m[:, 0], (pool, hit)
+        return (h + m[:, 0], pool), hit
+
+    def scan(h, layers, pool, **kw):
+        return jax.lax.scan(body, (h, pool),
+                            (layers, jnp.arange(pool.shape[0])), **kw)
 
     lead = cache.lead_pool
     if cfg.first_dense_layers:
-        h, (lead, _) = jax.lax.scan(body, h, (dense_layers(params), lead))
-    h, (pool, hit) = jax.lax.scan(body, h, (params["layers"], cache.k_pool),
-                                  unroll=cfg.scan_unroll)
+        (h, lead), _ = scan(h, dense_layers(params), lead)
+    (h, pool), hit = scan(h, params["layers"], cache.k_pool,
+                          unroll=cfg.scan_unroll)
     h = L.rms_norm(h, params["final_norm"], cfg.norm_eps)
     head = params.get("lm_head")
     if head is None:

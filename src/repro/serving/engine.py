@@ -148,10 +148,10 @@ class PagedModel:
         elif impl is not None:
             from repro.kernels import ops
 
-            def attn_fn(q, kp, vp, ks, vs, tbl, pos, window):
+            def attn_fn(q, kp, vp, ks, vs, tbl, pos, window, layer):
                 return ops.decode_attention(
                     q, kp, vp, pos, paged=True, block_table=tbl, k_scale=ks,
-                    v_scale=vs, window=window, impl=impl,
+                    v_scale=vs, window=window, impl=impl, layer=layer,
                 )
         pool_blocks = num_blocks
         self._pool_sharding = None
@@ -171,12 +171,13 @@ class PagedModel:
             self.params = jax.device_put(params, NamedSharding(mesh, P()))
             shard_pages = self._shard_pages
 
-            def attn_fn(q, kp, vp, ks, vs, tbl, pos, window):
+            def attn_fn(q, kp, vp, ks, vs, tbl, pos, window, layer):
                 # column j's page lies on its owning shard: reduce the
                 # global page id to that shard's local one
                 return ring_decode.ring_decode(
                     q, kp, vp, tbl % shard_pages, pos, mesh, axis=ring_axis,
                     window=window, k_scale=ks, v_scale=vs, impl=impl,
+                    layer=layer,
                 )
 
         self.cache = self._place(paged_cache.init_paged_cache(

@@ -21,12 +21,15 @@ Under latent attention (``cfg.mla``) the cache holds no per-head K/V:
 both keys and values, and ``v_pool`` is None. The leading dense layers,
 which run before the layer scan, keep theirs in ``lead_pool``
 (``(first_dense_layers, P, 1, bs, latent_dim)``), so that neither scan
-slices or re-stacks the other's pool. Each row is padded with zeros to a
-whole number of 128-lane tiles (``LANES``): 576 values take 640 (11 %
-more memory). At 576 the TPU compiler lays the stacked pool out with its
-page axis minor, and the decode program then relays each layer's pool out
-for the kernel and back, two copies of a layer's pool per layer and step;
-at 640 it keeps the tiled row-major layout the kernel reads.
+touches the other's pool. Each row is padded with zeros to a whole number
+of 128-lane tiles (``LANES``): 576 values take 640 (11 % more memory). The
+decode program carries every stacked pool through its layer scan and
+updates it in place, and the paged kernel reads a layer's pages straight
+out of the stack, in the tiled row-major layout. At 640 the TPU compiler
+gives the donated pool that layout; at 576 it lays the pool out with its
+page axis minor, and the decode program then relays the whole stack into
+the kernel's layout before the scan and back after it, two copies of the
+pool a step.
 
 Everything here is pure: writes return a new ``PagedKVCache`` (jit/donate
 friendly); allocation lives in the scheduler's ``BlockAllocator``.

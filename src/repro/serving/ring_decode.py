@@ -28,15 +28,16 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.kernels import registry
+from repro.kernels import ops, registry
 from repro.parallel import collectives
 
 __all__ = ["ring_decode", "ring_decode_reference"]
 
 
-def _shard_partial(q, k_pool, v_pool, block_table, position, *, base,
-                   window, scale, k_scale, v_scale, impl):
-    """One shard's paged decode partial: (o, lse), lse fp32 (B, H).
+def _shard_partial(q, k_pool, v_pool, block_table, position, *, layer,
+                   base, window, scale, k_scale, v_scale, impl):
+    """One shard's paged decode partial over layer ``layer`` of its stacked
+    pools: (o, lse), lse fp32 (B, H).
 
     Calls the registered impl directly (the partition-rule idiom) — this
     runs inside ``shard_map``, below the mesh-aware dispatch seam."""
@@ -44,41 +45,49 @@ def _shard_partial(q, k_pool, v_pool, block_table, position, *, base,
         "decode_attention", q, k_pool, v_pool, position, impl=impl,
         window=window, scale=scale, block_table=block_table,
         k_scale=k_scale, v_scale=v_scale, pos_offset=base, return_lse=True,
+        layer=layer,
     )
 
 
 def ring_decode(q, k_pool, v_pool, block_table, position, mesh, *,
                 axis: str = "data", window: int = 0, scale=None,
-                k_scale=None, v_scale=None, impl=None, overlap: bool = True):
+                k_scale=None, v_scale=None, impl=None, overlap: bool = True,
+                layer=None):
     """Decode against a cache sharded over ``mesh[axis]``.
 
     Args: ``q`` (B, H, D) and ``position`` (B,) — replicated; ``k_pool``/
     ``v_pool`` (P, K, bs, D) — sharded on P (rank r holds pages
-    ``[r*P/n, (r+1)*P/n)``); ``block_table`` (B, NB) — sharded on columns,
+    ``[r*P/n, (r+1)*P/n)``), or with ``layer`` the engine's stacked
+    (nl, P, K, bs, D) pools, sharded on P alike and read at that layer in
+    place; ``block_table`` (B, NB) — sharded on columns,
     with the convention that each entry indexes the *owning rank's local*
     pool (the engine's per-shard allocators hand out local page ids);
-    ``k_scale``/``v_scale`` — optional (P, K, bs, 1) pool scales, sharded
-    like the pools. ``overlap=False`` is the synchronous-ring oracle —
-    bit-identical fold values, only transfer issue order differs.
+    ``k_scale``/``v_scale`` — optional pool scales, (P, K, bs, 1) or
+    stacked, sharded like the pools. ``overlap=False`` is the
+    synchronous-ring oracle — bit-identical fold values, only transfer
+    issue order differs.
 
     Returns (B, H, D) in ``q.dtype``, replicated across ``axis`` and
     bitwise-equal to ``ring_decode_reference`` on the unsharded operands.
     """
+    layer, (k_pool, v_pool, k_scale, v_scale) = ops.stacked_pools(
+        layer, k_pool, v_pool, k_scale, v_scale)
     n = mesh.shape[axis]
     B, NB = block_table.shape
-    bs = k_pool.shape[2]
-    if NB % n or k_pool.shape[0] % n:
+    bs = k_pool.shape[3]
+    if NB % n or k_pool.shape[1] % n:
         raise ValueError(
             f"ring_decode: table columns ({NB}) and pool pages "
-            f"({k_pool.shape[0]}) must divide the {axis} axis ({n})"
+            f"({k_pool.shape[1]}) must divide the {axis} axis ({n})"
         )
     nb_l = NB // n
 
-    def local(q_l, k_l, v_l, tbl_l, pos_l, ks_l, vs_l):
+    def local(q_l, k_l, v_l, tbl_l, pos_l, ks_l, vs_l, layer_l):
         me = jax.lax.axis_index(axis)
         o_l, lse_l = _shard_partial(
-            q_l, k_l, v_l, tbl_l, pos_l, base=me * nb_l * bs, window=window,
-            scale=scale, k_scale=ks_l, v_scale=vs_l, impl=impl,
+            q_l, k_l, v_l, tbl_l, pos_l, layer=layer_l, base=me * nb_l * bs,
+            window=window, scale=scale, k_scale=ks_l, v_scale=vs_l,
+            impl=impl,
         )
         # rotate the partials; stash each at its GLOBAL shard index so the
         # final merge chain is identical (and the output replicated) on
@@ -105,22 +114,23 @@ def ring_decode(q, k_pool, v_pool, block_table, position, mesh, *,
             )
         return o_acc.astype(q_l.dtype)
 
-    pool_spec = P(axis, None, None, None)
+    pool_spec = P(None, axis, None, None, None)
     scale_spec = pool_spec if k_scale is not None else P()
     args = (q, k_pool, v_pool, block_table, position,
             k_scale if k_scale is not None else jnp.zeros(()),
-            v_scale if v_scale is not None else jnp.zeros(()))
+            v_scale if v_scale is not None else jnp.zeros(()),
+            jnp.asarray(layer, jnp.int32))
 
-    def wrapped(q_l, k_l, v_l, tbl_l, pos_l, ks_l, vs_l):
+    def wrapped(q_l, k_l, v_l, tbl_l, pos_l, ks_l, vs_l, layer_l):
         if k_scale is None:
             ks_l = vs_l = None
-        return local(q_l, k_l, v_l, tbl_l, pos_l, ks_l, vs_l)
+        return local(q_l, k_l, v_l, tbl_l, pos_l, ks_l, vs_l, layer_l)
 
     return jax.shard_map(
         wrapped,
         mesh=mesh,
         in_specs=(P(None, None, None), pool_spec, pool_spec, P(None, axis),
-                  P(None), scale_spec, scale_spec),
+                  P(None), scale_spec, scale_spec, P()),
         out_specs=P(None, None, None),
         check_vma=False,
     )(*args)
@@ -128,23 +138,25 @@ def ring_decode(q, k_pool, v_pool, block_table, position, mesh, *,
 
 def ring_decode_reference(q, k_pool, v_pool, block_table, position, n, *,
                           window: int = 0, scale=None, k_scale=None,
-                          v_scale=None, impl=None):
+                          v_scale=None, impl=None, layer=None):
     """Single-device simulation of the n-shard merge chain: the same
     per-shard paged partials, folded in the same global order — the
     bitwise oracle for ``ring_decode`` (and itself allclose to plain
     contiguous ``decode_attention``, which sums the cache in one scan
     rather than via the merge chain)."""
+    layer, (k_pool, v_pool, k_scale, v_scale) = ops.stacked_pools(
+        layer, k_pool, v_pool, k_scale, v_scale)
     B, NB = block_table.shape
-    bs = k_pool.shape[2]
+    bs = k_pool.shape[3]
     nb_l = NB // n
-    p_l = k_pool.shape[0] // n
+    p_l = k_pool.shape[1] // n
     o_acc = jnp.zeros(q.shape, jnp.float32)
     lse_acc = jnp.full(q.shape[:2], collectives.NEG_LSE, jnp.float32)
     for r in range(n):
-        sl = slice(r * p_l, (r + 1) * p_l)
+        sl = (slice(None), slice(r * p_l, (r + 1) * p_l))
         o_r, lse_r = _shard_partial(
             q, k_pool[sl], v_pool[sl],
-            block_table[:, r * nb_l:(r + 1) * nb_l], position,
+            block_table[:, r * nb_l:(r + 1) * nb_l], position, layer=layer,
             base=r * nb_l * bs, window=window, scale=scale,
             k_scale=None if k_scale is None else k_scale[sl],
             v_scale=None if v_scale is None else v_scale[sl],

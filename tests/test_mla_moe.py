@@ -5,6 +5,8 @@ layer, and an expert layer that holds a share of the routed experts.
 The plain reference here is written from the block's equations alone
 (float32, per token, every expert computed and weighted where chosen); it
 shares no code with the model but the parameter tree it reads."""
+import dataclasses
+import functools
 import math
 
 import jax
@@ -197,25 +199,38 @@ def test_masked_rows_reach_no_expert():
     np.testing.assert_allclose(out[keep], full[keep], atol=1e-6)
 
 
-def test_latent_kernel_matches_its_reference_forms():
+@pytest.mark.parametrize("pools", ["one", "stacked"])
+def test_latent_kernel_matches_its_reference_forms(pools):
     """Interpret mode of the Pallas kernel against the xla and ref forms:
     pages in no order, a partial last page, and an inactive row on the
-    scratch page."""
+    scratch page; and on the engine's stacked pool of 3 layers, each layer
+    read in place at its index, the same numbers as on that layer alone."""
     rng = np.random.default_rng(0)
     B, H, D, dv, bs, P, NB = 3, 4, 40, 32, 4, 12, 5
-    pool = jnp.asarray(rng.standard_normal((P, 1, bs, D)), jnp.float32)
+    stack = jnp.asarray(rng.standard_normal((3, P, 1, bs, D)), jnp.float32)
     q = jnp.asarray(rng.standard_normal((B, H, D)), jnp.float32)
     table = np.full((B, NB), NULL_BLOCK, np.int32)
     table[0, :3] = [7, 2, 9]  # position 9: the third page holds one row
     table[1, :5] = [3, 11, 1, 5, 8]  # position 19: all five, full
     pos = jnp.asarray([9, 19, 0], jnp.int32)  # row 2: an empty slot
-    outs = {impl: np.asarray(ops.latent_decode_attention(
-        q, pool, pos, jnp.asarray(table), v_width=dv, scale=0.3, impl=impl))
-        for impl in ("interpret", "xla", "ref")}
-    assert outs["ref"].shape == (B, H, dv)
-    for impl in ("interpret", "xla"):
-        np.testing.assert_allclose(outs[impl], outs["ref"], atol=1e-5,
-                                   rtol=1e-5)
+
+    def attend(pool, impl, **layer):
+        return np.asarray(ops.latent_decode_attention(
+            q, pool, pos, jnp.asarray(table), v_width=dv, scale=0.3,
+            impl=impl, **layer))
+
+    impls = ("interpret", "xla", "ref")
+    for layer in (range(3) if pools == "stacked" else [1]):
+        pool = stack[layer]
+        outs = {impl: attend(pool, impl) for impl in impls}
+        assert outs["ref"].shape == (B, H, dv)
+        for impl in ("interpret", "xla"):
+            np.testing.assert_allclose(outs[impl], outs["ref"], atol=1e-5,
+                                       rtol=1e-5)
+        if pools == "stacked":
+            for impl in impls:
+                np.testing.assert_array_equal(
+                    attend(stack, impl, layer=layer), outs[impl])
     # the values are the first dv columns of the very rows the keys are
     rows = np.asarray(pool)[table[0, :3], 0].reshape(-1, D)[:10]
     s = np.asarray(q)[0] @ rows.T * 0.3
@@ -281,6 +296,50 @@ def test_latent_pool_holds_only_the_latent():
         pool = np.asarray(pool)
         assert np.abs(pool[..., : cfg.latent_dim]).sum() > 0
         assert not pool[..., cfg.latent_dim:].any()
+
+
+@pytest.mark.parametrize("impl", ["xla", "interpret"])
+def test_latent_decode_step_writes_only_the_new_rows(params, impl):
+    """One decode step over the stacked latent pools, each carried through
+    its layer scan and updated in place: in every layer of both pools
+    exactly the batch's new rows change (the scratch page's among them),
+    and every other value keeps its bits."""
+    from repro.models import transformer
+    from repro.serving import paged_cache
+
+    rng = np.random.default_rng(4)
+    B, P, bs, NB = 3, 12, 4, 4
+    cache = paged_cache.init_paged_cache(SHARE, num_blocks=P, block_size=bs)
+    width = cache.k_pool.shape[-1]
+
+    def drawn(pool):
+        rows = rng.standard_normal(pool.shape[:-1] + (SHARE.latent_dim,))
+        pad = ((0, 0),) * (pool.ndim - 1) + ((0, width - SHARE.latent_dim),)
+        return jnp.asarray(np.pad(rows, pad), pool.dtype)
+
+    cache = dataclasses.replace(cache, k_pool=drawn(cache.k_pool),
+                                lead_pool=drawn(cache.lead_pool))
+    table = np.full((B, NB), NULL_BLOCK, np.int32)
+    table[0, :2] = [5, 2]
+    table[1, :4] = [7, 1, 11, 3]  # row 2: an empty slot on the scratch page
+    pos = np.asarray([6, 13, 0], np.int32)
+    batch = {"token": jnp.asarray([3, 9, 0], jnp.int32),
+             "position": jnp.asarray(pos),
+             "block_table": jnp.asarray(table)}
+    attn = functools.partial(ops.latent_decode_attention, impl=impl)
+    _, new = transformer.decode_step_paged(params, SHARE, cache, batch,
+                                           attn_fn=attn)
+    phys = table[np.arange(B), pos // bs]
+    for before, after in ((cache.k_pool, new.k_pool),
+                          (cache.lead_pool, new.lead_pool)):
+        before, after = np.asarray(before), np.asarray(after)
+        rows = np.zeros(before.shape[1:4], bool)  # (P, 1, bs)
+        rows[phys, 0, pos % bs] = True
+        np.testing.assert_array_equal(after[:, ~rows], before[:, ~rows])
+        written = after[:, phys, 0, pos % bs]  # (n, B, width)
+        assert (written[..., :SHARE.latent_dim]
+                != before[:, phys, 0, pos % bs][..., :SHARE.latent_dim]).all()
+        assert not written[..., SHARE.latent_dim:].any()
 
 
 def test_the_latent_pool_refuses_a_mesh_and_fp8():

@@ -160,10 +160,26 @@ _BOUNDARY = {"on": [15, 31, 47], "past": [16, 32, 48]}
 @pytest.mark.parametrize("kv_heads", [8, 2], ids=["mha", "gqa"])
 @pytest.mark.parametrize("window", [0, 13])
 @pytest.mark.parametrize("where", sorted(_BOUNDARY))
-def test_pallas_paged_matches_ref(rng, kv_heads, window, where):
+@pytest.mark.parametrize("pools", ["one", "stacked"])
+def test_pallas_paged_matches_ref(rng, kv_heads, window, where, pools):
     q, _, _, _, kp, vp, _, _, tbl = _paged_setup(rng, K=kv_heads)
     pos = jnp.asarray(_BOUNDARY[where], jnp.int32)
-    _kernel_vs_ref(q, kp, vp, pos, tbl, window=window)
+    if pools == "one":
+        _kernel_vs_ref(q, kp, vp, pos, tbl, window=window)
+        return
+    # the engine's stacked pools of 3 layers, each read in place at its
+    # index: the same numbers as the kernel and the ref on that layer alone
+    ks = jnp.asarray(rng.standard_normal((3,) + kp.shape), kp.dtype)
+    vs = jnp.asarray(rng.standard_normal((3,) + vp.shape), vp.dtype)
+    for layer in range(3):
+        got = ops.decode_attention(q, ks, vs, pos, paged=True,
+                                   block_table=tbl, window=window,
+                                   layer=layer, impl="interpret")
+        alone = ops.decode_attention(q, ks[layer], vs[layer], pos,
+                                     paged=True, block_table=tbl,
+                                     window=window, impl="interpret")
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(alone))
+        _kernel_vs_ref(q, ks[layer], vs[layer], pos, tbl, window=window)
 
 
 @pytest.mark.parametrize("pages", [1, 3, 8])
@@ -309,11 +325,13 @@ def test_paged_cache_quantized_write_matches_oracle(rng):
 # ---------------------------------------------------------------------------
 
 
-def test_decode_step_paged_bitwise_vs_contiguous():
+@pytest.mark.parametrize("arch", ["gemma-2b", "occamy-gptj"])
+def test_decode_step_paged_bitwise_vs_contiguous(arch):
+    # multi-query (gemma) and multi-head with a parallel block (GPT-J)
     from repro.configs.base import get_config
     from repro.models import registry as mreg, transformer
 
-    cfg = get_config("gemma-2b", reduced=True)
+    cfg = get_config(arch, reduced=True)
     params = mreg.init_params(cfg, jax.random.PRNGKey(0))
     rng = np.random.default_rng(3)
     B, S0, bs, nb = 2, 8, 4, 4
@@ -348,15 +366,25 @@ def test_decode_step_paged_bitwise_vs_contiguous():
     # stream identical (bs x D) tiles (bitwise needs matching partitions)
     from repro.kernels import registry as kreg
     with kreg.block_override("decode_attention", bs=bs):
-        want, _ = transformer.decode_step(
+        want, cache2 = transformer.decode_step(
             params, cfg, cache, {"token": tok, "position": posn})
     got, paged2 = transformer.decode_step_paged(
         params, cfg, paged,
         {"token": tok, "position": posn, "block_table": jnp.asarray(table)})
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    # the step wrote this position's K/V into the right page row
-    assert not np.array_equal(np.asarray(paged2.k_pool),
-                              np.asarray(paged.k_pool))
+    # in every layer's pool the step wrote exactly this position's B rows,
+    # the contiguous step's new K/V, and left every other row as it was
+    rows = np.zeros(kp.shape[1:4], bool)  # (P, K, bs)
+    phys = table[np.arange(B), S0 // bs]
+    rows[phys, :, S0 % bs] = True
+    for name, before, new in (("k", kp, paged2.k_pool),
+                              ("v", vp, paged2.v_pool)):
+        new = np.asarray(new)
+        np.testing.assert_array_equal(new[:, ~rows], before[:, ~rows])
+        written = new[:, phys][:, :, :, S0 % bs]  # (nl, B, K, hd)
+        np.testing.assert_array_equal(written,
+                                      np.asarray(cache2[name][..., S0, :]))
+        assert not np.array_equal(written, before[:, phys][:, :, :, S0 % bs])
 
 
 # ---------------------------------------------------------------------------

@@ -109,8 +109,34 @@ def test_paged_decode_attention_pallas_cell_geometry(one_chip, ring):
     assert "tpu_custom_call" in text
 
 
+_MOVES = re.compile(r"= \w+\[([\d,]*)\]\S* (copy|dynamic-slice|"
+                    r"dynamic-update-slice)\(")
+
+
+def _assert_pool_stays_in_place(compiled, cache):
+    """The decode program updates the stacked pools in place: its
+    temporaries stay under a tenth of the pools, and no copy, slice or
+    slice update has a result of a stacked pool's or one layer's pool's
+    shape (compared with unit dims dropped, as the compiler's bitcasts
+    drop them)."""
+    pools = [x for x in jax.tree.leaves(cache) if x.ndim == 5]
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in pools)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < pool_bytes / 10, (temp, pool_bytes)
+
+    def squeezed(shape):
+        return tuple(d for d in shape if d != 1)
+
+    shapes = {squeezed(s) for x in pools for s in (x.shape, x.shape[1:])}
+    moves = [m.group(0) for m in _MOVES.finditer(compiled.as_text())
+             if squeezed(int(d) for d in m.group(1).split(",") if d)
+             in shapes]
+    assert not moves, moves
+
+
 def test_gptj_decode_step_paged_fits_one_chip(one_chip):
     from repro.configs.base import get_config
+    from repro.kernels import registry as kernels
     from repro.models import registry, transformer
     from repro.serving import paged_cache
 
@@ -126,11 +152,15 @@ def test_gptj_decode_step_paged_fits_one_chip(one_chip):
     batch = on_chip({"token": jax.ShapeDtypeStruct((8,), I32),
                      "position": jax.ShapeDtypeStruct((8,), I32),
                      "block_table": jax.ShapeDtypeStruct((8, 16), I32)})
-    step = jax.jit(lambda p, c, b: transformer.decode_step_paged(p, cfg, c, b),
-                   donate_argnums=(1,))
-    mem = step.lower(params, cache, batch).compile().memory_analysis()
+    with kernels.default_impl("pallas"):
+        step = jax.jit(lambda p, c, b: transformer.decode_step_paged(
+            p, cfg, c, b), donate_argnums=(1,))
+        compiled = step.lower(params, cache, batch).compile()
+    mem = compiled.memory_analysis()
     held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert held < 16e9, held  # one v5e holds 16 GB
+    assert "tpu_custom_call" in compiled.as_text()
+    _assert_pool_stays_in_place(compiled, cache)
 
 
 def test_flash_attention_pallas_mla_prefill(one_chip):
@@ -184,6 +214,7 @@ def test_moonlight_decode_step_paged_fits_one_chip(one_chip):
     assert held < 16e9, held
     text = compiled.as_text()
     assert "%mla_decode_attention" in text and "ragged-dot" in text
+    _assert_pool_stays_in_place(compiled, cache)
 
 
 # Refused by the TPU compiler today; each needs its own kernel rework. A
